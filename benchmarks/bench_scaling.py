@@ -28,6 +28,9 @@ print(json.dumps({{"world": {W}, "step_s": rec.get("step_s"),
 def run():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # emulated host devices: the children never need (and, once this process
+    # has touched JAX, could not take) an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     for w in (1, 2, 8, 32, 128):
         out = subprocess.run([sys.executable, "-c", SCRIPT.replace("{W}", str(w))
                               .replace("{{", "@@").replace("}}", "%%")

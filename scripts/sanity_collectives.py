@@ -2,13 +2,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 import numpy as np
 
-from repro.dist.compat import make_mesh_compat, shard_map
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 axes = ("data", "model")
 WORLD = 8
 

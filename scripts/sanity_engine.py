@@ -8,14 +8,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import packed_embedding as pe
-from repro.dist.compat import make_mesh_compat, shard_map
 from repro.embedding.state import EmbeddingState
 from repro.engine import PicassoStrategy
+from repro.launch.mesh import make_mesh
 
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 AXES = ("data", "model")
 WORLD = 8
 RPS = 16            # rows per shard

@@ -7,6 +7,7 @@ map raw per-table IDs into the packed global row space.
 """
 from __future__ import annotations
 
+import zlib
 from typing import Dict, List, NamedTuple, Tuple
 
 import jax.numpy as jnp
@@ -50,7 +51,10 @@ def pack_group(group: PackedGroup, batch: Dict[str, Dict[str, jnp.ndarray]]) -> 
         raw = batch[f.name]["ids"]            # [B, L]
         w = batch[f.name]["weights"]          # [B, L]
         table = next(t for t in group.tables if t.name == s.table)
-        packed = scramble(raw, table.vocab, salt=hash(s.table) % 10007) + group.table_offsets[s.table]
+        # crc32, not hash(): str hashes are salted per process, which made
+        # the id -> row map (and the compiled step) differ from run to run
+        salt = zlib.crc32(s.table.encode()) % 10007
+        packed = scramble(raw, table.vocab, salt=salt) + group.table_offsets[s.table]
         ids_l.append(packed.astype(jnp.int32))
         if f.pooling == "mean":
             denom = jnp.clip(w.sum(axis=1, keepdims=True), 1e-9, None)

@@ -1,5 +1,4 @@
-"""Distributed execution utilities: sharding specs + jax version compat."""
-from repro.dist.compat import make_mesh_compat, shard_map
+"""Distributed execution utilities: sharding specs."""
 from repro.dist.sharding import (batch_specs, emb_specs, emb_state_specs,
                                  replicated, state_specs, to_named)
 
@@ -7,9 +6,7 @@ __all__ = [
     "batch_specs",
     "emb_specs",
     "emb_state_specs",
-    "make_mesh_compat",
     "replicated",
-    "shard_map",
     "state_specs",
     "to_named",
 ]

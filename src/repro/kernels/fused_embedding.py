@@ -212,21 +212,21 @@ def dedup_adagrad_pallas(
     si = jnp.take(sidx, order)
     # the sorted grads are materialized once up front ([m, D], same cost the
     # reference chain pays) and streamed through the block pipeline with the
-    # identity index map: a prefetch-driven gather map (o[i]) combined with
-    # ANY/aliased operands in one pallas_call mis-gathers on devices > 0
-    # under multi-device shard_map in interpret mode (jax 0.4.37)
+    # identity index map, not gathered by a prefetch-driven map (o[i]) next
+    # to the ANY/aliased operands: that combination once mis-gathered on
+    # devices > 0 under multi-device shard_map in interpret mode
     sg = jnp.take(g, order, axis=0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,   # si
         grid=(m,),
         in_specs=[
             pl.BlockSpec((1, d), lambda i, si: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, d), g.dtype),
@@ -294,7 +294,7 @@ def tier_probe_pallas(
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, h), lambda i, u, v: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1), lambda i, u, v: (i, 0)),
@@ -369,7 +369,7 @@ def gather_project_pallas(
         grid=(n,),
         in_specs=[
             pl.BlockSpec((nd, d), lambda i, ix, k: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, d), lambda i, ix, k: (i, 0)),

@@ -1,21 +1,27 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the Pallas path is used; on CPU (this container) the pure-jnp
-reference executes (XLA fuses it well), while tests exercise the kernels in
-``interpret=True`` mode against the same references. Set
-``REPRO_FORCE_PALLAS_INTERPRET=1`` to route *all* calls through the
-interpreted kernels (slow; correctness soak).
+On TPU each op follows ``TPU_KERNELS``: the compiled Pallas kernel where the
+table says ``True``, otherwise the pure-jnp reference for the reason the
+table states. Nothing is decided by catching a compile error, and a TPU
+process never interprets a kernel. Off TPU the reference executes (XLA fuses
+it well), while tests exercise the kernels in ``interpret=True`` mode
+against the same references; ``REPRO_FORCE_PALLAS_INTERPRET=1`` routes
+*all* calls there through the interpreted kernels (slow; correctness soak).
+The variable has no effect on a TPU.
 
 Backend dispatch is resolved ONCE, at the first dispatched call (not inside
 every traced call): the env var and ``jax.default_backend()`` are read one
 time and cached, so the hot path never re-reads ``os.environ``. Call
 ``reset_backend_cache()`` after changing either (tests do).
+``dispatch_lines`` renders what every op runs in this process, for the
+launchers to print at startup.
 
 The sparse hot-path ops (``gather_pool`` / ``segment_grad`` /
-``dedup_adagrad`` / ``tier_probe``) additionally take an explicit
-``fused=`` override: ``None`` follows the backend default above, ``True``
-forces the Pallas kernels (interpreted off-TPU), ``False`` forces the jnp
-reference. ``resolve_fused`` maps the user-facing
+``dedup_adagrad`` / ``tier_probe`` / ``gather_project`` and the routed-grad
+codecs) additionally take an explicit ``fused=`` override: ``None`` follows
+the backend default above, ``True`` asks for the Pallas kernel (interpreted
+off-TPU; on TPU only where ``TPU_KERNELS`` allows it), ``False`` forces the
+jnp reference. ``resolve_fused`` maps the user-facing
 ``TrainConfig/ServeConfig.use_fused_kernels`` spelling (``'auto' | bool |
 'on' | 'off'``) to that override once, at engine construction —
 strategies then carry a plain static bool through their traces.
@@ -31,7 +37,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -55,7 +61,46 @@ from repro.kernels.interaction_bwd import (cross_layer_bwd_pallas,
                                            dot_interaction_bwd_pallas,
                                            fm_interaction_bwd_pallas)
 
-# (use_pallas, interpret), resolved once at first dispatch
+_ROW = ("(1, d) blocks and one-row DMAs of a [rows, 10] f32 array are "
+        "refused (not aligned to the 8 x 128 tiling)")
+
+#: What each op runs on a TPU: ``True`` is the compiled Pallas kernel, a
+#: string is why the op runs its jnp reference there instead. Every reason
+#: is a compile refusal at the DeepFM main path's shapes (d=10, 16384 x 39
+#: ids per step); tests/test_tpu_compile.py compiles each kernel for a v5e
+#: chip and fails when this table and the compiler disagree.
+TPU_KERNELS: Dict[str, Union[bool, str]] = {
+    "embedding_bag": f"{_ROW}; an 8-row-block variant compiles, but at "
+                     "16384 x 39 ids its prefetched index vectors (3 x 5 MB) "
+                     "overflow the 1 MiB SMEM",
+    "gather_pool": "runs the embedding_bag kernel",
+    "segment_grad": "runs the embedding_bag kernel",
+    "dedup_adagrad": f"{_ROW}; XLA stores the master column-major "
+                     "({0,1:T(8,128)}), so an in-place kernel would pay a "
+                     "relayout copy of the whole table per step",
+    "tier_probe": "(1, 1) hit/slot output blocks refused, and the whole "
+                  "sorted key vector is sliced dynamically in VMEM",
+    "gather_project": "(1, d) and (1, nd) output blocks refused",
+    "gather_project_grad": "(1, d) and (1, nd) input blocks refused",
+    "fm_interaction": True,
+    "fm_interaction_bwd": True,
+    "dot_interaction": True,
+    "dot_interaction_bwd": "Mosaic: unsupported shape cast",
+    "cross_layer": "Mosaic: unimplemented primitive dynamic_slice",
+    "cross_layer_bwd": True,
+    "fp16_compress": "Mosaic: failed to legalize tpu.pack_subelements",
+    "fp16_decompress": "Mosaic: invalid vector type for load",
+    "topk_compress": True,
+    "topk_decompress": True,
+}
+
+#: the ops that take the engine's ``fused=`` override
+_SPARSE_OPS = frozenset({
+    "gather_pool", "segment_grad", "dedup_adagrad", "tier_probe",
+    "gather_project", "gather_project_grad", "fp16_compress",
+    "fp16_decompress", "topk_compress", "topk_decompress"})
+
+# (on_tpu, interpret-soak), resolved once at first dispatch
 _BACKEND: Optional[Tuple[bool, bool]] = None
 
 
@@ -63,11 +108,40 @@ def _backend() -> Tuple[bool, bool]:
     global _BACKEND
     if _BACKEND is None:
         tpu = jax.default_backend() == "tpu"
-        force = bool(os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"))
-        # force wins on every backend — a TPU soak must actually interpret,
-        # not silently run the compiled kernels
-        _BACKEND = (tpu or force, force or not tpu)
+        # the soak is an off-TPU tool: a TPU process never interprets
+        force = not tpu and bool(os.environ.get("REPRO_FORCE_PALLAS_INTERPRET"))
+        _BACKEND = (tpu, force)
     return _BACKEND
+
+
+def _kernel_on(op: str, fused: Optional[bool] = None) -> bool:
+    """Whether this call of ``op`` runs its Pallas kernel (else reference):
+    on TPU the table decides (``fused=False`` can still opt out), off TPU
+    the interpret soak or an explicit ``fused`` does."""
+    tpu, force = _backend()
+    if tpu:
+        return fused is not False and TPU_KERNELS[op] is True
+    return force if fused is None else bool(fused)
+
+
+def dispatch_lines(fused: Optional[bool] = None) -> List[str]:
+    """The backend, then one line per op saying what it runs in this
+    process — the table the launchers print at startup. ``fused`` is the
+    engine's resolved ``use_fused_kernels`` (it steers the sparse ops
+    only)."""
+    tpu, _ = _backend()
+    lines = [f"backend {jax.default_backend()}, interpret mode "
+             f"{'on' if _interpret() else 'off'}; kernels:"]
+    for op, entry in TPU_KERNELS.items():
+        f = fused if op in _SPARSE_OPS else None
+        if _kernel_on(op, f):
+            what = "pallas" if tpu else "pallas (interpret)"
+        elif tpu and entry is not True and f is not False:
+            what = f"reference: {entry}"
+        else:
+            what = "reference"
+        lines.append(f"  {op:20s} {what}")
+    return lines
 
 
 # spelling -> resolved bool, memoized per process so repeated engine
@@ -86,37 +160,34 @@ def reset_backend_cache() -> None:
     _RESOLVE_MEMO.clear()
 
 
-def _use_pallas() -> bool:
-    return _backend()[0]
-
-
 def _interpret() -> bool:
-    return _backend()[1]
+    """Pallas kernels are interpreted exactly when not on a TPU."""
+    return not _backend()[0]
 
 
 def interpret_mode() -> bool:
     """Whether Pallas kernels run through the interpreter in this process
-    (TPU-less backend or the ``REPRO_FORCE_PALLAS_INTERPRET`` soak). Public
-    so the bench harness can stamp its rows — interpreter timings must never
-    be mistaken for silicon numbers."""
+    (any TPU-less backend). Public so the bench harness can stamp its rows —
+    interpreter timings must never be mistaken for silicon numbers."""
     return _interpret()
 
 
 def resolve_fused(spec: Union[str, bool, None]) -> bool:
     """Map a ``use_fused_kernels`` spelling to a static bool, once.
 
-    ``'auto'``/``None`` follow the backend (Pallas on TPU or under the
-    interpret-soak env var, reference on CPU); booleans and ``'on'``/
-    ``'off'`` force it. Raises on anything else so config typos fail at
-    construction, not silently at dispatch. Resolutions are memoized per
-    spelling; ``reset_backend_cache`` clears the memo together with the
-    backend decision it is derived from."""
+    ``'auto'``/``None`` follow the backend (on for TPU, where
+    ``TPU_KERNELS`` then gates each op, and under the interpret-soak env
+    var; reference elsewhere); booleans and ``'on'``/``'off'`` force it.
+    Raises on anything else so config typos fail at construction, not
+    silently at dispatch. Resolutions are memoized per spelling;
+    ``reset_backend_cache`` clears the memo together with the backend
+    decision it is derived from."""
     try:
         return _RESOLVE_MEMO[spec]
     except KeyError:
         pass
     if spec is None or spec == "auto":
-        out = _use_pallas()
+        out = any(_backend())
     elif isinstance(spec, bool):
         out = spec
     elif spec == "on":
@@ -128,10 +199,6 @@ def resolve_fused(spec: Union[str, bool, None]) -> bool:
             f"use_fused_kernels must be 'auto', 'on', 'off' or a bool; got {spec!r}")
     _RESOLVE_MEMO[spec] = out
     return out
-
-
-def _fused(fused: Optional[bool]) -> bool:
-    return _use_pallas() if fused is None else bool(fused)
 
 
 # ---------------------------------------------------------------------------
@@ -153,7 +220,7 @@ def _fused(fused: Optional[bool]) -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _embedding_bag(table, ids, seg, w, n_bags: int):
-    if _use_pallas():
+    if _kernel_on("embedding_bag"):
         # the kernel wants explicit weights; the reference keeps its
         # weightless fast path (no [n, D] multiply by runtime ones)
         wp = w if w is not None else jnp.ones_like(ids, table.dtype)
@@ -187,7 +254,7 @@ def embedding_bag(table, ids, seg, n_bags: int, weights: Optional[jnp.ndarray] =
 
 @jax.custom_vjp
 def fm_interaction(fields):
-    if _use_pallas():
+    if _kernel_on("fm_interaction"):
         return fm_interaction_pallas(fields, interpret=_interpret())
     return ref.fm_interaction_ref(fields)
 
@@ -197,7 +264,7 @@ def _fm_fwd(fields):
 
 
 def _fm_bwd(fields, g):
-    if _use_pallas():
+    if _kernel_on("fm_interaction_bwd"):
         return (fm_interaction_bwd_pallas(fields, g, interpret=_interpret()),)
     _, vjp = jax.vjp(ref.fm_interaction_ref, fields)
     return vjp(g)
@@ -208,7 +275,7 @@ fm_interaction.defvjp(_fm_fwd, _fm_bwd)
 
 @jax.custom_vjp
 def dot_interaction(fields):
-    if _use_pallas():
+    if _kernel_on("dot_interaction"):
         return dot_interaction_pallas(fields, interpret=_interpret())
     return ref.dot_interaction_ref(fields)
 
@@ -218,7 +285,7 @@ def _dot_fwd(fields):
 
 
 def _dot_bwd(fields, g):
-    if _use_pallas():
+    if _kernel_on("dot_interaction_bwd"):
         return (dot_interaction_bwd_pallas(fields, g, interpret=_interpret()),)
     _, vjp = jax.vjp(ref.dot_interaction_ref, fields)
     return vjp(g)
@@ -229,7 +296,7 @@ dot_interaction.defvjp(_dot_fwd, _dot_bwd)
 
 @jax.custom_vjp
 def cross_layer(x0, x, w, b):
-    if _use_pallas():
+    if _kernel_on("cross_layer"):
         return cross_layer_pallas(x0, x, w, b, interpret=_interpret())
     return ref.cross_layer_ref(x0, x, w, b)
 
@@ -239,7 +306,7 @@ def _cross_fwd(x0, x, w, b):
 
 
 def _cross_bwd(res, g):
-    if _use_pallas():
+    if _kernel_on("cross_layer_bwd"):
         return cross_layer_bwd_pallas(*res, g, interpret=_interpret())
     _, vjp = jax.vjp(ref.cross_layer_ref, *res)
     return vjp(g)
@@ -253,31 +320,35 @@ cross_layer.defvjp(_cross_fwd, _cross_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _gather_pool_impl(rows_u, inv, weights, seg, n_bags: int, fused: bool):
-    if fused:
+def _gather_pool_impl(rows_u, inv, weights, seg, n_bags: int,
+                      fused: Optional[bool]):
+    if _kernel_on("gather_pool", fused):
         return gather_pool_pallas(rows_u, inv, weights, seg, n_bags,
                                   interpret=_interpret())
     return ref.gather_pool_ref(rows_u, inv, weights, seg, n_bags)
 
 
-def _segment_grad_impl(g_bags, seg, weights, inv, n_rows: int, fused: bool):
-    if fused:
+def _segment_grad_impl(g_bags, seg, weights, inv, n_rows: int,
+                       fused: Optional[bool]):
+    if _kernel_on("segment_grad", fused):
         return segment_grad_pallas(g_bags, seg, weights, inv, n_rows,
                                    interpret=_interpret())
     return ref.segment_grad_ref(g_bags, seg, weights, inv, n_rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _gather_pool(rows_u, inv, weights, seg, n_bags: int, fused: bool):
+def _gather_pool(rows_u, inv, weights, seg, n_bags: int,
+                 fused: Optional[bool]):
     return _gather_pool_impl(rows_u, inv, weights, seg, n_bags, fused)
 
 
-def _gather_pool_fwd(rows_u, inv, weights, seg, n_bags: int, fused: bool):
+def _gather_pool_fwd(rows_u, inv, weights, seg, n_bags: int,
+                     fused: Optional[bool]):
     out = _gather_pool_impl(rows_u, inv, weights, seg, n_bags, fused)
     return out, (inv, weights, seg, rows_u.shape[0])
 
 
-def _gather_pool_bwd(n_bags: int, fused: bool, res, g):
+def _gather_pool_bwd(n_bags: int, fused: Optional[bool], res, g):
     inv, weights, seg, n_rows = res
     g_rows = _segment_grad_impl(g, seg, weights, inv, n_rows, fused)
     # weights are pooling constants (see module docstring): zero cotangent
@@ -292,15 +363,14 @@ def gather_pool(rows_u, inv, weights, seg, n_bags: int,
     """Fused forward SegmentReduction ``bags[seg] += w * rows_u[inv]`` with a
     fused-transpose custom VJP. Requires ``seg`` sorted ascending and
     covering every bag (the packed-batch layout guarantees it)."""
-    return _gather_pool(rows_u, inv, weights, seg, int(n_bags), _fused(fused))
+    return _gather_pool(rows_u, inv, weights, seg, int(n_bags), fused)
 
 
 def segment_grad(g_bags, seg, weights, inv, n_rows: int,
                  fused: Optional[bool] = None):
     """Transpose of ``gather_pool`` as a standalone op (the engine's explicit
     backward path): ``g_rows[u] = sum_{inv[i]=u} w[i] * g_bags[seg[i]]``."""
-    return _segment_grad_impl(g_bags, seg, weights, inv, int(n_rows),
-                              _fused(fused))
+    return _segment_grad_impl(g_bags, seg, weights, inv, int(n_rows), fused)
 
 
 def dedup_adagrad(w, acc, idx, g, valid, lr: float, eps: float,
@@ -310,7 +380,7 @@ def dedup_adagrad(w, acc, idx, g, valid, lr: float, eps: float,
     kernel accumulates duplicates in the reference order — untouched rows
     stay bitwise identical, touched rows match to ~1 ULP of XLA-fusion
     reassociation in the adagrad arithmetic."""
-    if _fused(fused):
+    if _kernel_on("dedup_adagrad", fused):
         return dedup_adagrad_pallas(w, acc, idx, g, valid, float(lr),
                                     float(eps), interpret=_interpret())
     return ref.dedup_adagrad_ref(w, acc, idx, g, valid, lr, eps)
@@ -320,40 +390,40 @@ def tier_probe(uniq, uvalid, keys, rows, fused: Optional[bool] = None):
     """Probe one sorted-key cache tier: ``(hit, slot, rows)`` with miss rows
     exactly zero. ``slot`` is the clamped searchsorted position (the
     backward scatter reuses it)."""
-    if _fused(fused):
+    if _kernel_on("tier_probe", fused):
         return tier_probe_pallas(uniq, uvalid, keys, rows,
                                  interpret=_interpret())
     return ref.tier_probe_ref(uniq, uvalid, keys, rows)
 
 
-def _gather_project_impl(back, idx, kept, proj, fused: bool):
-    if fused:
+def _gather_project_impl(back, idx, kept, proj, fused: Optional[bool]):
+    if _kernel_on("gather_project", fused):
         return gather_project_pallas(back, idx, kept, proj,
                                      interpret=_interpret())
     return ref.gather_project_ref(back, idx, kept, proj)
 
 
 def _gather_project_grad_impl(g_wide, g_narrow, idx, kept, proj, m: int,
-                              fused: bool):
-    if fused:
+                              fused: Optional[bool]):
+    if _kernel_on("gather_project_grad", fused):
         return gather_project_grad_pallas(g_wide, g_narrow, idx, kept, proj,
                                           m, interpret=_interpret())
     return ref.gather_project_grad_ref(g_wide, g_narrow, idx, kept, proj, m)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gather_project(back, idx, kept, proj, fused: bool):
+def _gather_project(back, idx, kept, proj, fused: Optional[bool]):
     return _gather_project_impl(back, idx, kept, proj, fused)
 
 
-def _gather_project_fwd(back, idx, kept, proj, fused: bool):
+def _gather_project_fwd(back, idx, kept, proj, fused: Optional[bool]):
     out = _gather_project_impl(back, idx, kept, proj, fused)
     # the narrow residual is already kept-masked, so the projection cotangent
     # below needs no re-mask
     return out, (idx, kept, out[1], proj, back.shape[0])
 
 
-def _gather_project_bwd(fused: bool, res, g):
+def _gather_project_bwd(fused: Optional[bool], res, g):
     idx, kept, narrow, proj, m = res
     g_wide, g_narrow = g
     g_back = _gather_project_grad_impl(g_wide, g_narrow, idx, kept, proj,
@@ -375,7 +445,7 @@ def gather_project(back, idx, kept, proj, fused: Optional[bool] = None):
     ``[n, d]``-then-``[n, D]`` chain in either direction), and the
     projection's gradient is one ``narrow^T @ g_wide`` matmul off the
     forward's residual."""
-    return _gather_project(back, idx, kept, proj, _fused(fused))
+    return _gather_project(back, idx, kept, proj, fused)
 
 
 def gather_project_grad(g_wide, g_narrow, idx, kept, proj, m: int,
@@ -384,7 +454,7 @@ def gather_project_grad(g_wide, g_narrow, idx, kept, proj, m: int,
     (the engine's explicit backward path): ``g_back[j] = sum_{idx[i]=j}
     kept[i] * (g_wide[i] @ proj^T + g_narrow[i])``."""
     return _gather_project_grad_impl(g_wide, g_narrow, idx, kept, proj,
-                                     int(m), _fused(fused))
+                                     int(m), fused)
 
 
 # ---------------------------------------------------------------------------
@@ -397,13 +467,13 @@ def compress_fp16(g, fused: Optional[bool] = None):
     """Per-row amax scale + float16 cast: ``(q [m, D] f16, scale [m, 1] f32)``.
     All-zero rows compress to exact zeros (padded bucket slots roundtrip
     bitwise)."""
-    if _fused(fused):
+    if _kernel_on("fp16_compress", fused):
         return fp16_compress_pallas(g, interpret=_interpret())
     return ref.fp16_compress_ref(g)
 
 
 def decompress_fp16(q, scale, fused: Optional[bool] = None):
-    if _fused(fused):
+    if _kernel_on("fp16_decompress", fused):
         return fp16_decompress_pallas(q, scale, interpret=_interpret())
     return ref.fp16_decompress_ref(q, scale)
 
@@ -411,13 +481,13 @@ def decompress_fp16(q, scale, fused: Optional[bool] = None):
 def compress_topk(g, k: int, fused: Optional[bool] = None):
     """Per-row magnitude top-k sparsification: ``(vals [m, k], idx [m, k])``,
     descending magnitude, ties toward the lower index."""
-    if _fused(fused):
+    if _kernel_on("topk_compress", fused):
         return topk_compress_pallas(g, int(k), interpret=_interpret())
     return ref.topk_compress_ref(g, int(k))
 
 
 def decompress_topk(vals, idx, d: int, fused: Optional[bool] = None):
-    if _fused(fused):
+    if _kernel_on("topk_decompress", fused):
         return topk_decompress_pallas(vals, idx, int(d),
                                       interpret=_interpret())
     return ref.topk_decompress_ref(vals, idx, int(d))

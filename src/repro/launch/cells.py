@@ -21,7 +21,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import LMConfig, SchNetConfig, ShapeSpec, WDLConfig, get_config, get_shapes
 from repro.core.packing import PicassoPlan, make_plan
 from repro.data.synthetic import batch_spec
-from repro.dist.compat import shard_map
 from repro.dist.sharding import batch_specs, state_specs, to_named
 from repro.embedding.state import abstract_embedding_state
 from repro.layers.transformer import (abstract_kv_cache, abstract_lm_params, lm_decode_step,
@@ -331,9 +330,9 @@ def make_schnet_step(cfg: SchNetConfig, mesh, d_feat: int, batched: bool, lr=1e-
         return sh
 
     def wrapped(params, opt, batch):
-        f = shard_map(local_step, mesh=mesh,
-                      in_specs=(rep, orep, batch_spec_fn(batch)),
-                      out_specs=(rep, orep, P()), check_vma=False)
+        f = jax.shard_map(local_step, mesh=mesh,
+                          in_specs=(rep, orep, batch_spec_fn(batch)),
+                          out_specs=(rep, orep, P()), check_vma=False)
         return f(params, opt, batch)
 
     return jax.jit(wrapped, donate_argnums=(0, 1)), params, opt, rep, orep, batch_spec_fn

@@ -20,8 +20,6 @@ from repro.launch.roofline import collective_bytes, count_ops, roofline_terms  #
 
 def _measure(compiled, world: int) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     wire, per_op = collective_bytes(hlo, world)
     return {"flops": float(cost.get("flops", 0.0)),

@@ -64,9 +64,10 @@ def main():
                          "its backend stamp matches this process")
     ap.add_argument("--fused-kernels", default="auto",
                     choices=("auto", "on", "off"),
-                    help="fused Pallas sparse kernels: 'auto' wherever "
-                         "Pallas runs (TPU / REPRO_FORCE_PALLAS_INTERPRET), "
-                         "'on' forces them, 'off' forces the jnp reference")
+                    help="fused Pallas sparse kernels: 'auto' on a TPU "
+                         "(as the printed kernel table allows) and off-TPU "
+                         "under REPRO_FORCE_PALLAS_INTERPRET=1, 'on' forces "
+                         "them, 'off' forces the jnp reference")
     ap.add_argument("--reload-dir", default="", metavar="DIR",
                     help="pick up model deltas a streaming trainer publishes "
                          "(repro.launch.train --stream --publish-dir DIR): "
@@ -94,6 +95,9 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.core.packing import make_plan
@@ -130,6 +134,10 @@ def main():
         resolve_assignment(plan, spec, world=world, use_cache=use_cache)
         return ServeConfig(strategy=spec, use_cache=use_cache,
                            use_fused_kernels=args.fused_kernels)
+
+    from repro.kernels import ops
+    for line in ops.dispatch_lines(ops.resolve_fused(args.fused_kernels)):
+        print(f"[serve] {line}")
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.retrieval:

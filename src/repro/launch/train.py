@@ -4,13 +4,22 @@ tolerance.
 
   PYTHONPATH=src python -m repro.launch.train --arch deepfm --smoke \\
       --steps 100 --global-batch 256 --devices 8 --mesh 4x2
+
+``main(argv, cfg)`` is the same run called from Python (``chip_smoke.py``
+drives it so): ``cfg`` replaces the ``--arch``/``--smoke`` config, and the
+return value is the run record.
 """
 import argparse
 import os
 import time
 
 
-def main():
+def main(argv=None, cfg=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return
+    ``{"history": [...], "batches": {...}}``: one ``{step, loss, cache_hits,
+    overflow, seconds}`` entry per logged step (``seconds`` is the wall time
+    per step since the previous log line, first step's compile included),
+    and the batch prefetcher's ``produced``/``backup_served`` counters."""
     # registry import is jax-importing but backend-lazy: XLA_FLAGS set after
     # parsing (for --devices) is still honoured at first device query.
     from repro.engine import AUTO_NAMES, available_strategies
@@ -76,10 +85,11 @@ def main():
                     choices=("auto", "on", "off"),
                     help="fused Pallas sparse kernels (gather+pool custom "
                          "VJP, dedup+adagrad scatter, tier probes): 'auto' "
-                         "uses them wherever Pallas runs (TPU, or any "
-                         "backend under REPRO_FORCE_PALLAS_INTERPRET=1), "
-                         "'on' forces them (interpreted off-TPU, slow), "
-                         "'off' forces the reference jnp chains")
+                         "uses them on a TPU (each op as the printed kernel "
+                         "table allows) and off-TPU under "
+                         "REPRO_FORCE_PALLAS_INTERPRET=1, 'on' forces them "
+                         "(interpreted off-TPU, slow), 'off' forces the "
+                         "reference jnp chains")
     ap.add_argument("--overlap", default="auto",
                     choices=("off", "on", "auto"),
                     help="software-pipelined train step: 'on' double-buffers "
@@ -153,7 +163,7 @@ def main():
     ap.add_argument("--lr-emb", type=float, default=0.05)
     ap.add_argument("--lr-dense", type=float, default=1e-3)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.replan_iters < 0:
         ap.error("--replan-iters must be >= 0 (0 disables replanning)")
     if args.reshard_at and not args.reshard_to:
@@ -168,6 +178,9 @@ def main():
 
     import jax
     import numpy as np
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     # recovery events (rollbacks, quarantines, counter resets) are the
     # operator's window into the fault-tolerance subsystem: surface the
@@ -184,8 +197,8 @@ def main():
     from repro.launch.mesh import make_mesh
     from repro.models.wdl import WDLModel
     from repro.runtime import (AnomalyGuard, ChaosController, Replanner,
-                               apply_plan_meta, make_submesh,
-                               parse_fault_plan, parse_mesh_shape, plan_meta,
+                               apply_plan_meta, parse_fault_plan,
+                               parse_mesh_shape, plan_meta,
                                publish_state, reshard_live, restore_elastic,
                                run_stream)
     from repro.train.checkpoint import (AsyncCheckpointer, latest_step,
@@ -210,7 +223,8 @@ def main():
             grid="tiny" if args.smoke else "small",
             log=lambda s: print(f"[train] calib {s}", flush=True))
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    if cfg is None:
+        cfg = get_config(args.arch, smoke=args.smoke)
     plan = make_plan(cfg, world=world, per_device_batch=args.global_batch // world,
                      enable_packing=not args.no_packing,
                      enable_cache=not args.no_cache,
@@ -230,6 +244,7 @@ def main():
             print(f"[train] resumed plan rev {plan.rev} from checkpoint meta "
                   f"(strategy: {sorted(set(plan.strategy.values()))})")
     from repro.engine import maybe_compile
+    from repro.kernels import ops
     if plan.strategy:
         # the plan already carries an assignment (checkpoint meta) — 'mixed'
         # makes every engine follow it instead of recompiling from priors
@@ -311,6 +326,8 @@ def main():
     print(f"[train] {cfg.name}: {len(plan.groups)} packed groups, "
           f"micro={plan.microbatch}, ilv={len(plan.interleave)} waves, "
           f"world={world}, plan rev={plan.rev}")
+    for line in ops.dispatch_lines(ops.resolve_fused(args.fused_kernels)):
+        print(f"[train] {line}")
 
     # positional stream factory: ``make_source(i)`` opens the synthetic
     # stream at absolute batch index ``i`` on the CURRENT mesh (read at call
@@ -324,18 +341,28 @@ def main():
                          learnable=args.learnable, start=start),
             mesh, lambda b: batch_specs(b, axes))
 
-    stream = ReplayableStream(make_source)
-    if chaos is not None:
-        stream = chaos.wrap_stream(stream)
+    source = ReplayableStream(make_source)
+    stream = source if chaos is None else chaos.wrap_stream(source)
 
     active_ckpt = None  # the live AsyncCheckpointer (chaos ckpt@ targets it)
+    history = []
+    t_log, step_log = time.perf_counter(), 0  # time and step of the last log
 
     def on_metrics(step, m):
+        nonlocal t_log, step_log
         if replanner is not None:
             replanner.observe(m)
         if step % args.log_every == 0:
-            print(f"  step {step:5d} loss={float(m['loss']):.4f} "
-                  f"hits={int(m['cache_hits'])} ovf={int(m['overflow'])}", flush=True)
+            rec = {"step": step, "loss": float(m["loss"]),
+                   "cache_hits": int(m["cache_hits"]),
+                   "overflow": int(m["overflow"])}
+            now = time.perf_counter()  # float(loss) above waited for the step
+            rec["seconds"] = (now - t_log) / max(step - step_log, 1)
+            t_log, step_log = now, step
+            history.append(rec)
+            print(f"  step {step:5d} loss={rec['loss']:.4f} "
+                  f"hits={rec['cache_hits']} ovf={rec['overflow']} "
+                  f"{rec['seconds'] * 1e3:.1f}ms/step", flush=True)
         if chaos is not None:
             if args.ckpt_dir:
                 chaos.after_checkpoint(step, args.ckpt_dir, active_ckpt)
@@ -364,7 +391,7 @@ def main():
         print(f"[train] reshard world {world} -> {new_world} "
               f"(mesh {'x'.join(map(str, new_shape))}) at step {step}",
               flush=True)
-        new_mesh = make_submesh(new_shape, axes)
+        new_mesh = make_mesh(new_shape, axes)
         plan, state = reshard_live(
             plan, state, new_world, args.global_batch // new_world,
             mesh=new_mesh, axes=axes, mesh_shape=new_shape,
@@ -441,9 +468,11 @@ def main():
             on_segment=on_segment)
         if ckpt is not None:
             ckpt.wait()
+        batches = source.stats
         stream.close()
+        print(f"[train] batches {batches}")
         print(f"[train] stream done at step {last} (world={world})")
-        return
+        return {"history": history, "batches": batches}
 
     if args.ckpt_dir:
         sup = Supervisor(args.ckpt_dir, ckpt_every=args.ckpt_every,
@@ -494,11 +523,11 @@ def main():
                     sup.ckpt.wait()
     else:
         it = iter(stream)
+        t_log = time.perf_counter()
         for i in range(1, args.steps + 1):
-            try:
-                batch = next(it)
-            except StopIteration:  # stream ended/stalled: finish gracefully,
-                break              # matching the Supervisor path's semantics
+            batch = next(it, None)
+            if batch is None:  # the synthetic stream is endless
+                raise RuntimeError(f"batch stream ended before step {i}")
             state, m = step_fn(state, batch)
             on_metrics(i, m)
             if reshard_pending and i >= args.reshard_at and i < args.steps:
@@ -511,7 +540,11 @@ def main():
         n_mig = sum(1 for e in replanner.events if e.migrated)
         print(f"[train] replans: {len(replanner.events)} attempted, "
               f"{n_mig} migrated, final plan rev={plan.rev}")
+    batches = source.stats
+    stream.close()
+    print(f"[train] batches {batches}")
     print("[train] done")
+    return {"history": history, "batches": batches}
 
 
 if __name__ == "__main__":

@@ -11,6 +11,7 @@ features) and produces ``logits [B, n_tasks]``.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -38,6 +39,22 @@ class WDLModel:
         v = self.fidx[name]
         out = pooled[v.gid][:, v.bag_offset:v.bag_offset + v.n_bags, :]
         return out[:, 0, :] if v.n_bags == 1 and self.cfg.field_by_name(name).pooling != "none" else out
+
+    def field_stack(self, pooled: Dict[int, jnp.ndarray], names: List[str]) -> jnp.ndarray:
+        """``[B, len(names), D]``: the pooled (one-bag) fields ``names``, all
+        of width D. Fields in consecutive bags of one group come out as one
+        slice of ``pooled``, so the backward pads once per run and not once
+        per field: DeepFM's 39 per-field ``[B, 39, 10]`` pads would each be
+        lane-padded 13x on a TPU and together overflow the chip's memory."""
+        runs: List[List[int]] = []
+        for n in names:
+            v = self.fidx[n]
+            if runs and runs[-1][0] == v.gid and runs[-1][2] == v.bag_offset:
+                runs[-1][2] += 1
+            else:
+                runs.append([v.gid, v.bag_offset, v.bag_offset + 1])
+        parts = [pooled[g][:, a:z, :] for g, a, z in runs]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
     def field_mask(self, batch: Dict, name: str) -> jnp.ndarray:
         return batch["fields"][name]["weights"] > 0
@@ -140,7 +157,13 @@ class WDLModel:
             dx = batch["dense"]
             dense_proc = mlp(params["bottom"], dx) if cfg.dense_arch else dx
 
-        base_parts = [self.field_emb(pooled, f.name) for f in self.pooled_fields]
+        by_dim: Dict[int, List[str]] = {}
+        for f in self.pooled_fields:
+            by_dim.setdefault(f.dim, []).append(f.name)
+        # runs of equal width keep field order in the concatenated base
+        base_parts = [self.field_stack(pooled, [f.name for f in fs]).reshape(b, -1)
+                      for _, fs in itertools.groupby(self.pooled_fields,
+                                                     key=lambda f: f.dim)]
         if dense_proc is not None:
             base_parts.append(dense_proc)
         base = jnp.concatenate(base_parts, axis=-1) if base_parts else jnp.zeros((b, 0))
@@ -151,22 +174,22 @@ class WDLModel:
         for n, it in enumerate(cfg.interactions):
             name = f"i{n}_{it.kind}"
             if it.kind == "linear":
-                for f in self.pooled_fields:
-                    wide_logit = wide_logit + self.field_emb(pooled, f.name) @ params[name][f.name]
+                for names in by_dim.values():
+                    wt = jnp.stack([params[name][fn] for fn in names])  # [F, D, 1]
+                    wide_logit = wide_logit + jnp.einsum(
+                        "bfd,fdo->bo", self.field_stack(pooled, names), wt)
             elif it.kind == "fm":
-                by_dim: Dict[int, List[jnp.ndarray]] = {}
-                for f in self.pooled_fields:
-                    by_dim.setdefault(f.dim, []).append(self.field_emb(pooled, f.name))
-                for es in by_dim.values():
-                    if len(es) > 1:
-                        wide_logit = wide_logit + I.fm_interaction(jnp.stack(es, axis=1))
+                for names in by_dim.values():
+                    if len(names) > 1:
+                        wide_logit = wide_logit + I.fm_interaction(
+                            self.field_stack(pooled, names))
             elif it.kind == "dot":
-                dims = [f.dim for f in self.pooled_fields]
-                d0 = dims[0]
-                es = [self.field_emb(pooled, f.name) for f in self.pooled_fields if f.dim == d0]
+                d0 = self.pooled_fields[0].dim
+                es = self.field_stack(pooled, [f.name for f in self.pooled_fields
+                                               if f.dim == d0])
                 if dense_proc is not None and dense_proc.shape[-1] == d0:
-                    es.append(dense_proc)
-                deep_parts.append(I.dot_interaction(jnp.stack(es, axis=1)))
+                    es = jnp.concatenate([es, dense_proc[:, None, :]], axis=1)
+                deep_parts.append(I.dot_interaction(es))
             elif it.kind == "cross":
                 deep_parts.append(I.cross_net(params[name], base))
             elif it.kind == "self_attn_seq":

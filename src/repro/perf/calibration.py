@@ -157,9 +157,9 @@ def _wire_mesh():
     the local-copy cost, which is the honest world=1 wire price)."""
     import jax
 
-    from repro.dist.compat import make_submesh_compat
+    from repro.launch.mesh import make_mesh
 
-    return make_submesh_compat((len(jax.devices()),), ("wire",))
+    return make_mesh((len(jax.devices()),), ("wire",))
 
 
 def _bench_wire(kind: str, per_shard_kb: int, mesh,
@@ -168,8 +168,6 @@ def _bench_wire(kind: str, per_shard_kb: int, mesh,
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.dist.compat import shard_map
 
     world = int(mesh.devices.size)
     m = max(1, (per_shard_kb * 1024 // 4) // max(world, 1))
@@ -188,9 +186,9 @@ def _bench_wire(kind: str, per_shard_kb: int, mesh,
             return jax.lax.all_gather(y, "wire", axis=0, tiled=True)
 
     x = jax.device_put(x, NamedSharding(mesh, P("wire", None)))
-    f = jax.jit(shard_map(local, mesh=mesh, in_specs=P("wire", None),
-                          out_specs=P("wire", None) if kind == "wire_a2a"
-                          else P(None, None), check_vma=False))
+    f = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P("wire", None),
+                              out_specs=P("wire", None) if kind == "wire_a2a"
+                              else P(None, None), check_vma=False))
     us = _time(f, x, **it)
     return float(world * m * 4), us
 

@@ -13,9 +13,8 @@ harness that proves the recovery paths.
 """
 from repro.runtime.chaos import (ChaosController, ChaosFailure, ChaosStream,
                                  FaultPlan, parse_fault_plan)
-from repro.runtime.elastic import (make_submesh, parse_mesh_shape,
-                                   place_state, reshard_live,
-                                   restore_elastic)
+from repro.runtime.elastic import (parse_mesh_shape, place_state,
+                                   reshard_live, restore_elastic)
 from repro.runtime.guard import AnomalyGuard, AnomalyRollback, GuardConfig
 from repro.runtime.replanner import (ReplanEvent, Replanner, apply_plan_meta,
                                      plan_delta, plan_meta)
@@ -35,7 +34,6 @@ __all__ = [
     "Replanner",
     "apply_plan_meta",
     "load_published",
-    "make_submesh",
     "parse_fault_plan",
     "parse_mesh_shape",
     "place_state",

@@ -33,7 +33,6 @@ from typing import Any, Optional, Sequence, Tuple, Union
 import jax
 
 from repro.core.packing import PicassoPlan, reshard_plan
-from repro.dist.compat import make_submesh_compat
 from repro.dist.sharding import emb_specs, replicated, state_specs, to_named
 from repro.embedding.state import migrate_state, reshard_state
 from repro.train.checkpoint import load_checkpoint_meta, restore_checkpoint
@@ -51,11 +50,6 @@ def parse_mesh_shape(spec: Union[str, Sequence[int]], n_axes: int = 2
     if len(shape) < n_axes:
         shape = shape + (1,) * (n_axes - len(shape))
     return shape
-
-
-def make_submesh(shape: Sequence[int], axes: Sequence[str]):
-    """Mesh over the first ``prod(shape)`` devices (scale-down in-process)."""
-    return make_submesh_compat(shape, axes)
 
 
 def place_state(state: Any, plan: PicassoPlan, mesh, axes) -> Any:

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.features import field_index, pack_group
 from repro.core.packing import PicassoPlan
-from repro.dist.compat import shard_map
 from repro.dist.sharding import batch_specs, emb_specs, replicated
 from repro.engine import EmbeddingEngine
 from repro.models.wdl import WDLModel
@@ -78,9 +77,9 @@ def make_serve_step(model: WDLModel, plan: PicassoPlan, mesh, axes, global_batch
         return jax.nn.sigmoid(logits)
 
     def wrapped(state, batch):
-        f = shard_map(local_fn, mesh=mesh,
-                      in_specs=(especs, rep, batch_specs(batch, axes)),
-                      out_specs=P(axes, None), check_vma=False)
+        f = jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(especs, rep, batch_specs(batch, axes)),
+                          out_specs=P(axes, None), check_vma=False)
         return f(state["emb"], state["dense"], batch)
 
     return jax.jit(wrapped)
@@ -174,9 +173,9 @@ def make_retrieval_step(model: WDLModel, plan: PicassoPlan, mesh, axes,
 
     def wrapped(state, batch, cand_ids):
         bspec = jax.tree.map(lambda x: P(*((None,) * len(x.shape))), batch)
-        f = shard_map(local_fn, mesh=mesh,
-                      in_specs=(especs, rep, bspec, P(axes)),
-                      out_specs=(P(), P()), check_vma=False)
+        f = jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(especs, rep, bspec, P(axes)),
+                          out_specs=(P(), P()), check_vma=False)
         return f(state["emb"], state["dense"], batch, cand_ids)
 
     return jax.jit(wrapped)

@@ -41,9 +41,16 @@ class StepFailure(RuntimeError):
 
 
 #: exception types where a restore-and-replay retry cannot help: the same
-#: code will deterministically fail again (tracing/shape/type bugs, broken
-#: imports) or the process itself is compromised (host OOM).
-FATAL_TYPES = (TypeError, AttributeError, ImportError, NameError, MemoryError)
+#: code will deterministically fail again (tracing/shape/type bugs, kernel
+#: lowering refusals, broken imports) or the process itself is compromised
+#: (host OOM).
+FATAL_TYPES = (TypeError, AttributeError, ImportError, NameError, MemoryError,
+               ValueError, NotImplementedError)
+
+#: message markers of device-side failures a retry cannot help: the device
+#: is out of memory, or the compiler (XLA or Mosaic) refused the program
+FATAL_MARKERS = ("RESOURCE_EXHAUSTED", "failed to compile",
+                 "compile permanent error")
 
 
 def classify_failure(e: BaseException) -> str:
@@ -52,9 +59,16 @@ def classify_failure(e: BaseException) -> str:
     Transient is the default: node loss, filesystem hiccups, injected chaos,
     and guard rollback requests all surface as RuntimeError/OSError
     subclasses. ``AnomalyRollback`` is transient by construction — the whole
-    point of raising it is to trigger the restore path.
+    point of raising it is to trigger the restore path. An exception is
+    fatal when it, or any exception it was raised from, is a
+    ``FATAL_TYPES`` instance or names a ``FATAL_MARKERS`` failure.
     """
-    return "fatal" if isinstance(e, FATAL_TYPES) else "transient"
+    while e is not None:
+        if isinstance(e, FATAL_TYPES) or any(m in str(e)
+                                             for m in FATAL_MARKERS):
+            return "fatal"
+        e = e.__cause__
+    return "transient"
 
 
 class Supervisor:

@@ -57,7 +57,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.features import PackedBatch, pack_group
 from repro.core.interleaving import pipeline_handoff, resolve_overlap
 from repro.core.packing import PicassoPlan
-from repro.dist.compat import shard_map
 from repro.dist.sharding import batch_specs, emb_specs, state_specs, to_named
 from repro.embedding.state import EmbeddingState
 from repro.engine import EmbeddingEngine
@@ -234,10 +233,10 @@ def make_train_step(model: WDLModel, plan: PicassoPlan, mesh, axes: Tuple[str, .
 
     def wrapped(state, batch):
         bspecs = batch_specs(batch, axes)
-        f = shard_map(local_step, mesh=mesh,
-                      in_specs=(sspecs, bspecs),
-                      out_specs=(sspecs, mspecs),
-                      check_vma=False)
+        f = jax.shard_map(local_step, mesh=mesh,
+                          in_specs=(sspecs, bspecs),
+                          out_specs=(sspecs, mspecs),
+                          check_vma=False)
         return f(state, batch)
 
     jit_kw = {}
@@ -283,8 +282,8 @@ def make_flush_fn(plan: PicassoPlan, mesh, axes: Tuple[str, ...],
     especs = emb_specs(plan, axes)
 
     def wrapped(state):
-        f = shard_map(engine.flush, mesh=mesh, in_specs=(especs,),
-                      out_specs=especs, check_vma=False)
+        f = jax.shard_map(engine.flush, mesh=mesh, in_specs=(especs,),
+                          out_specs=especs, check_vma=False)
         return {**state, "emb": f(state["emb"])}
 
     return jax.jit(wrapped, donate_argnums=(0,))

@@ -2,9 +2,16 @@
 # device; multi-device behaviour is covered by subprocess tests
 # (test_distributed.py) which set --xla_force_host_platform_device_count
 # in the child process only.
-import pytest
+import os
 
-from repro.launch.mesh import make_test_mesh
+# the suite runs on the CPU wherever it is started: on a machine with a TPU
+# the pytest process must not take the chip, and the subprocess tests
+# inherit the setting
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import pytest  # noqa: E402
+
+from repro.launch.mesh import make_test_mesh  # noqa: E402
 
 
 @pytest.fixture(scope="session")

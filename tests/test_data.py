@@ -6,7 +6,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.data.graph import molecule_batch, pad_subgraph, sample_neighbors, synthetic_graph
-from repro.data.pipeline import Prefetcher
+from repro.data.pipeline import BatchProducerError, Prefetcher, ReplayableStream
 from repro.data.synthetic import make_batch, zipf_ids
 
 
@@ -75,6 +75,48 @@ def test_prefetcher_close_unblocks_immediately_when_idle():
     assert [next(pf) for _ in range(3)] == [0, 1, 2]
     pf.close()
     assert not pf._thread.is_alive()
+
+
+def test_prefetcher_producer_death_raises():
+    """A producer that raises must surface its error, never end the stream
+    as if it were exhausted (a trainer would report a short run as done)."""
+    def gen():
+        yield 0
+        raise OSError("disk gone")
+
+    pf = Prefetcher(gen(), depth=2, timeout_s=5.0)
+    assert next(pf) == 0
+    t0 = time.monotonic()
+    with pytest.raises(BatchProducerError) as ei:
+        next(pf)
+    assert isinstance(ei.value.__cause__, OSError)
+    assert time.monotonic() - t0 < 2.0  # seen at once, not after the timeout
+    pf.close()
+
+
+def test_prefetcher_timeout_without_backup_raises():
+    def gen():
+        time.sleep(10)
+        yield 0
+
+    pf = Prefetcher(gen(), depth=2, timeout_s=0.2)
+    with pytest.raises(TimeoutError):
+        next(pf)
+    assert pf.stats["backup_served"] == 0
+    pf.close()
+
+
+def test_replayable_stream_stats_survive_reopen():
+    rs = ReplayableStream(lambda start: Prefetcher(iter(range(start, start + 3)),
+                                                   depth=2))
+    assert [next(rs) for _ in range(2)] == [0, 1]
+    rs.seek(0)
+    assert next(rs) == 0
+    deadline = time.monotonic() + 2.0
+    while rs.stats["produced"] < 5 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert rs.stats == {"produced": 5, "backup_served": 0}
+    rs.close()
 
 
 def test_neighbor_sampler_valid():

@@ -27,7 +27,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_test_mesh
 """
 
@@ -209,8 +209,8 @@ print("DIFF", max(abs(a-b) for a,b in zip(traj[True], traj[False])))
 
 def test_fused_kernels_parity_8dev():
     """Fused Pallas sparse kernels == reference chains on a real 4x2 mesh
-    (regression: jax-0.4.37 interpret-mode prefetch-gather index maps
-    combined with aliased ANY operands mis-gathered on devices > 0 — the
+    (regression: interpret-mode prefetch-gather index maps combined with
+    aliased ANY operands once mis-gathered on devices > 0 — the
     dedup kernel now pre-sorts its grads outside the kernel, and this test
     pins multi-device parity end to end, warm hot tier included)."""
     out = _run(HEADER + """
@@ -263,7 +263,8 @@ from repro.core.packing import make_plan
 from repro.data.synthetic import make_batch
 from repro.dist.sharding import batch_specs, to_named
 from repro.models.wdl import WDLModel
-from repro.runtime import make_submesh, place_state, reshard_live
+from repro.launch.mesh import make_mesh
+from repro.runtime import place_state, reshard_live
 from repro.train.train_step import TrainConfig, init_state, make_train_step
 axes = ("data", "model"); GB = 32
 fields = (FeatureField("a", 1001, 8, max_len=2),
@@ -299,14 +300,14 @@ state = init_state(WDLModel(cfg, plan8), plan8, jax.random.PRNGKey(0),
 state, _ = seg(build(plan8, mesh8), state, mesh8, seed=10, n=4)
 
 # ---- live reshard 8 -> 4 and snapshot the migrated state to host --------
-mesh4 = make_submesh((2, 2), axes)
+mesh4 = make_mesh((2, 2), axes)
 plan4, state = reshard_live(plan8, state, 4, GB // 4, mesh=mesh4, axes=axes,
                             mesh_shape=(2, 2))
 assert plan4.strategy == MIX, plan4.strategy
 snap = jax.device_get(state)
 
 # ---- continued run: 3 steps at 4, live reshard 4 -> 2, 3 steps at 2 -----
-mesh2 = make_submesh((1, 2), axes)
+mesh2 = make_mesh((1, 2), axes)
 state, ls_b = seg(build(plan4, mesh4), state, mesh4, seed=11, n=3)
 plan2, state = reshard_live(plan4, state, 2, GB // 2, mesh=mesh2, axes=axes,
                             mesh_shape=(1, 2))

@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import packed_embedding as pe
 from repro.core.hashing import scramble, scramble_np
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.embedding.bag import embedding_bag
 from repro.embedding.state import EmbeddingState
 from repro.engine import PicassoStrategy

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.data.pipeline import ReplayableStream
+from repro.data.pipeline import BatchProducerError, ReplayableStream
 from repro.data.synthetic import batch_stream
 from repro.dist.sharding import batch_specs, to_named
 from repro.models.wdl import WDLModel
@@ -292,6 +292,35 @@ def test_supervisor_fatal_classification_short_circuits(tmp_path):
         sup.run(_toy_state(), _toy_step(), _toy_stream(), n_steps=10,
                 fail_injector=inject)
     assert sup.total_failures == 0  # never entered the retry path
+
+
+class _MosaicLikeError(Exception):
+    """Stands in for the kernel compiler's error type (not a public class)."""
+
+
+@pytest.mark.parametrize("exc, kind", [
+    (ValueError("block shape must be divisible by 8 and 128"), "fatal"),
+    (NotImplementedError("Unimplemented primitive: dynamic_slice"), "fatal"),
+    (_MosaicLikeError("INTERNAL: Mosaic failed to compile TPU kernel"),
+     "fatal"),
+    (RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+                  "hbm"), "fatal"),
+    (RuntimeError("UNAVAILABLE: connection to peer lost"), "transient"),
+    (OSError("checkpoint write failed"), "transient"),
+])
+def test_classify_failure_device_errors(exc, kind):
+    """Lowering refusals, compile errors and device OOM repeat on every
+    retry: they must re-raise, not enter the restore loop."""
+    assert classify_failure(exc) == kind
+
+
+def test_classify_failure_follows_cause():
+    for cause, kind in ((TypeError("bad batch"), "fatal"),
+                        (OSError("read timed out"), "transient")):
+        try:
+            raise BatchProducerError("batch producer thread died") from cause
+        except BatchProducerError as e:
+            assert classify_failure(e) == kind
 
 
 # --------------------------------------------------- checkpoint corruption

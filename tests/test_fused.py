@@ -23,7 +23,7 @@ from repro.core import packed_embedding as pe
 from repro.core.features import pack_group
 from repro.core.packing import make_plan
 from repro.data.synthetic import make_batch
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.sharding import batch_specs, emb_specs, replicated, to_named
 from repro.embedding.state import EmbeddingState, init_embedding_state
 from repro.engine import EmbeddingEngine
@@ -375,9 +375,8 @@ def _l2_engine_step(mesh, fused, cache_update="psum"):
     st = init_embedding_state(jax.random.PRNGKey(1), plan)[gid]
     batch = make_batch(cfg, GB, np.random.default_rng(2))
     fields = jax.tree.map(jnp.asarray, batch["fields"])
-    # warm the tiers with ids the batch actually queries: pack_group's
-    # scramble salt is hash()-based (randomized per process), so fixed key
-    # ranges would only hit by luck of PYTHONHASHSEED
+    # warm the tiers with ids the batch actually queries: pack_group
+    # scrambles raw ids, so fixed key ranges would only hit by luck
     pb = pack_group(plan.groups[0], fields)
     uids = np.unique(np.asarray(pb.ids))
     rows_padded = st.w.shape[0]
@@ -519,16 +518,31 @@ def test_backend_dispatch_cached_and_resettable(monkeypatch):
         # (the CI soak sets REPRO_FORCE_PALLAS_INTERPRET for the whole file)
         monkeypatch.delenv("REPRO_FORCE_PALLAS_INTERPRET", raising=False)
         ops.reset_backend_cache()
-        assert ops._use_pallas() == tpu
+        assert ops._kernel_on("fm_interaction") == tpu
         # cached: setting the env var mid-process has NO effect...
         monkeypatch.setenv("REPRO_FORCE_PALLAS_INTERPRET", "1")
-        assert ops._use_pallas() == tpu
+        assert ops._kernel_on("fm_interaction") == tpu
         # ...until the cache is reset (what a fresh process does)
         ops.reset_backend_cache()
-        assert ops._use_pallas() is True
+        assert ops._kernel_on("fm_interaction") is True
         assert ops.resolve_fused("auto") is True
+        # on a TPU the soak does nothing and the table decides each op:
+        # never interpreted, a refused kernel stays on its reference even
+        # when asked for, and the printed table says why
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        ops.reset_backend_cache()
+        assert not ops.interpret_mode()
+        assert ops.resolve_fused("auto") is True
+        assert ops._kernel_on("fm_interaction") is True
+        assert ops._kernel_on("gather_pool", True) is False
+        assert ops._kernel_on("fm_interaction", False) is False
+        lines = dict(ln.split(None, 1) for ln in ops.dispatch_lines(True)[1:])
+        assert lines["fm_interaction"] == "pallas"
+        assert lines["gather_pool"] == \
+            f"reference: {ops.TPU_KERNELS['gather_pool']}"
     finally:
-        ops.reset_backend_cache()  # monkeypatch restores the env at teardown
+        monkeypatch.undo()
+        ops.reset_backend_cache()
     assert ops.resolve_fused(True) is True
     assert ops.resolve_fused("off") is False
     with pytest.raises(ValueError, match="use_fused_kernels"):

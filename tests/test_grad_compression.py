@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.kernels import ref
 from repro.optim.grad_compression import (ROUTED_MODES, compress_rows,
                                           compressed_all_gather,

@@ -15,7 +15,7 @@ from repro.core import packed_embedding as pe
 from repro.core.features import pack_group
 from repro.core.packing import make_plan
 from repro.data.synthetic import make_batch
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.sharding import batch_specs, emb_specs, replicated, to_named
 from repro.embedding.state import EmbeddingState, init_embedding_state
 from repro.engine import (EmbeddingEngine, PicassoL2Strategy, PicassoStrategy,
