@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.kernels import ops
 from repro.optim import grad_compression as gcomp
 
@@ -93,7 +94,8 @@ def partition(uniq: jnp.ndarray, miss: jnp.ndarray, rows_per_shard: int, world: 
 
 def _a2a(x: jnp.ndarray, axes: Axes) -> jnp.ndarray:
     """all_to_all over (possibly multiple) mesh axes; [world, ...] layout."""
-    return lax.all_to_all(x, axes, split_axis=0, concat_axis=0, tiled=True)
+    with obs.scope(obs.SHUFFLE):
+        return lax.all_to_all(x, axes, split_axis=0, concat_axis=0, tiled=True)
 
 
 def _compressed_a2a_rows(send_g: jnp.ndarray, axes: Axes, world: int,
@@ -148,6 +150,8 @@ class LookupCtx(NamedTuple):
     #   (picasso_narrow only: the gather_project residual — zero at tier-hit
     #   and padded positions — from which the projection gradient is one
     #   ``narrow^T @ g_u`` matmul in the backward)
+    n_uniq: Optional[jnp.ndarray] = None  # scalar: distinct ids looked up
+    #   (``UniqueResult.n_uniq``; None where the lookup does not dedup)
 
 
 def cache_probe(uniq: jnp.ndarray, uvalid: jnp.ndarray,
@@ -159,6 +163,59 @@ def cache_probe(uniq: jnp.ndarray, uvalid: jnp.ndarray,
     p_c = jnp.clip(p, 0, hot_keys.shape[0] - 1)
     hit = (hot_keys[p_c] == uniq) & uvalid
     return hit, p_c
+
+
+def _probe_tiers(u: UniqueResult, hot_keys, hot_rows, l2_keys, l2_rows,
+                 fused: bool):
+    """The tiered probe, under the ``tier_probe`` scope: L1 (``hot_keys``)
+    first, then the L2 tier for the L1 misses only. Returns ``(hit,
+    cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows, miss)``,
+    ``l2_*`` None without an L2 tier.
+
+    The probe is the search: which ids a tier holds, and at which slot. The
+    hit rows are fetched by the Stitch (``_stitch_tiers``), except where the
+    ``tier_probe`` kernel runs (``ops.runs_kernel``): it fetches each hit row
+    in its search pass, and returns it zero-masked as ``*_probe_rows`` (else
+    None)."""
+    kernel = ops.runs_kernel("tier_probe", fused)
+    with obs.scope(obs.TIER_PROBE):
+        if (kernel and hot_keys is not None and hot_keys.shape[0] > 0
+                and hot_rows is not None):
+            hit, slot, l1_rows = ops.tier_probe(u.uniq, u.uvalid, hot_keys,
+                                                hot_rows, fused=True)
+        else:
+            hit, slot = cache_probe(u.uniq, u.uvalid, hot_keys)
+            l1_rows = None
+        if l2_keys is None or l2_keys.shape[0] == 0:
+            return hit, slot, l1_rows, None, None, None, u.uvalid & ~hit
+        if kernel:
+            l2_hit, l2_slot, l2_rows_p = ops.tier_probe(
+                u.uniq, u.uvalid & ~hit, l2_keys, l2_rows, fused=True)
+        else:
+            l2_hit, l2_slot = cache_probe(u.uniq, u.uvalid & ~hit, l2_keys)
+            l2_rows_p = None
+        return (hit, slot, l1_rows, l2_hit, l2_slot, l2_rows_p,
+                u.uvalid & ~hit & ~l2_hit)
+
+
+def _stitch_tiers(miss_rows, hit, cache_slot, hot_rows, l1_probe_rows,
+                  l2_hit, l2_slot, l2_rows, l2_probe_rows):
+    """Stitch the tier hits over the routed-back rows: L2 hits first, then
+    L1 hits. Where the probe kernel returned its rows (``*_probe_rows``)
+    they are taken as they are, else the tier's rows are gathered at the
+    probed slot."""
+    if l2_hit is not None:
+        l2 = (l2_probe_rows if l2_probe_rows is not None
+              else jnp.take(l2_rows, l2_slot, axis=0))
+        miss_rows = jnp.where(l2_hit[:, None], l2.astype(miss_rows.dtype),
+                              miss_rows)
+    if l1_probe_rows is not None:
+        return jnp.where(hit[:, None], l1_probe_rows.astype(miss_rows.dtype),
+                         miss_rows)
+    if hot_rows is not None and hot_rows.shape[0] > 0:
+        hot = jnp.take(hot_rows, cache_slot, axis=0)
+        return jnp.where(hit[:, None], hot.astype(miss_rows.dtype), miss_rows)
+    return miss_rows
 
 
 def mp_lookup(
@@ -187,69 +244,50 @@ def mp_lookup(
 
     ``fused=True`` replaces each tier's searchsorted/take/where chain with
     one ``ops.tier_probe`` kernel pass (binary search + hit-masked row
-    gather); the probed rows come back zero-masked, so the Stitch below is a
-    single ``where`` per tier and hit values are identical either way.
+    gather) where that kernel runs (``_probe_tiers``); the probed rows come
+    back zero-masked, so the Stitch below is a single ``where`` per tier and
+    hit values are identical either way.
     """
     rps, d = table_shard.shape
     rows_padded = rps * world
     n = ids.shape[0]
 
-    u = fixed_unique(ids, sentinel=rows_padded)
-    probe_l1 = (fused and hot_keys is not None and hot_keys.shape[0] > 0
-                and hot_rows is not None)
-    if probe_l1:
-        hit, cache_slot, l1_probe_rows = ops.tier_probe(
-            u.uniq, u.uvalid, hot_keys, hot_rows, fused=True)
-    else:
-        hit, cache_slot = cache_probe(u.uniq, u.uvalid, hot_keys)
-    use_l2 = l2_keys is not None and l2_keys.shape[0] > 0
-    if use_l2:
-        if fused:
-            l2_hit, l2_slot, l2_probe_rows = ops.tier_probe(
-                u.uniq, u.uvalid & ~hit, l2_keys, l2_rows, fused=True)
-        else:
-            l2_hit, l2_slot = cache_probe(u.uniq, u.uvalid & ~hit, l2_keys)
-        miss = u.uvalid & ~hit & ~l2_hit
-    else:
-        l2_hit, l2_slot = None, None
-        miss = u.uvalid & ~hit
-    r = partition(u.uniq, miss, rps, world, capacity)
+    with obs.scope(obs.UNIQUE):
+        u = fixed_unique(ids, sentinel=rows_padded)
+    (hit, cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows,
+     miss) = _probe_tiers(u, hot_keys, hot_rows, l2_keys, l2_rows, fused)
+    with obs.scope(obs.PARTITION):
+        r = partition(u.uniq, miss, rps, world, capacity)
+        send_ids = jnp.full((world * capacity,), -1, jnp.int32)
+        send_ids = send_ids.at[r.send_slot].set(u.uniq.astype(jnp.int32),
+                                                mode="drop")
 
     # ---- Shuffle: route miss ids to owners --------------------------------
-    send_ids = jnp.full((world * capacity,), -1, jnp.int32)
-    send_ids = send_ids.at[r.send_slot].set(u.uniq.astype(jnp.int32), mode="drop")
     recv_ids = _a2a(send_ids.reshape(world, capacity), axes)  # [world, cap]
 
-    my = lax.axis_index(axes)
-    base = my.astype(jnp.int32) * rps
-    recv_valid = recv_ids >= 0
-    recv_local = jnp.clip(recv_ids - base, 0, rps - 1)
-
     # ---- local Gather ------------------------------------------------------
-    served = jnp.take(table_shard, recv_local.reshape(-1), axis=0)
-    served = served * recv_valid.reshape(-1, 1).astype(served.dtype)
+    with obs.scope(obs.GATHER):
+        my = lax.axis_index(axes)
+        base = my.astype(jnp.int32) * rps
+        recv_valid = recv_ids >= 0
+        recv_local = jnp.clip(recv_ids - base, 0, rps - 1)
+        served = jnp.take(table_shard, recv_local.reshape(-1), axis=0)
+        served = served * recv_valid.reshape(-1, 1).astype(served.dtype)
 
     # ---- Shuffle back + Stitch ---------------------------------------------
     back = _a2a(served.reshape(world, capacity, d), axes).reshape(world * capacity, d)
-    take_idx = jnp.minimum(r.send_slot, world * capacity - 1)
-    miss_rows = jnp.take(back, take_idx, axis=0) * r.kept[:, None].astype(served.dtype)
-
-    if use_l2:
-        l2 = l2_probe_rows if fused else jnp.take(l2_rows, l2_slot, axis=0)
-        miss_rows = jnp.where(l2_hit[:, None], l2.astype(miss_rows.dtype), miss_rows)
-    if probe_l1:
-        rows_u = jnp.where(hit[:, None], l1_probe_rows.astype(miss_rows.dtype),
-                           miss_rows)
-    elif hot_rows is not None and hot_rows.shape[0] > 0:
-        hot = jnp.take(hot_rows, cache_slot, axis=0)
-        rows_u = jnp.where(hit[:, None], hot.astype(miss_rows.dtype), miss_rows)
-    else:
-        rows_u = miss_rows
+    with obs.scope(obs.STITCH):
+        take_idx = jnp.minimum(r.send_slot, world * capacity - 1)
+        miss_rows = (jnp.take(back, take_idx, axis=0)
+                     * r.kept[:, None].astype(served.dtype))
+        rows_u = _stitch_tiers(miss_rows, hit, cache_slot, hot_rows,
+                               l1_probe_rows, l2_hit, l2_slot, l2_rows,
+                               l2_probe_rows)
 
     ctx = LookupCtx(
         uniq=u.uniq, inv=u.inv, uvalid=u.uvalid, hit=hit, cache_slot=cache_slot,
         routing=r, recv_ids=recv_ids, recv_local=recv_local, recv_valid=recv_valid,
-        l2_hit=l2_hit, l2_slot=l2_slot,
+        l2_hit=l2_hit, l2_slot=l2_slot, n_uniq=u.n_uniq,
     )
     return rows_u, ctx
 
@@ -284,65 +322,43 @@ def mp_lookup_narrow(
     rps, nd = table_shard.shape
     rows_padded = rps * world
 
-    u = fixed_unique(ids, sentinel=rows_padded)
-    probe_l1 = (fused and hot_keys is not None and hot_keys.shape[0] > 0
-                and hot_rows is not None)
-    if probe_l1:
-        hit, cache_slot, l1_probe_rows = ops.tier_probe(
-            u.uniq, u.uvalid, hot_keys, hot_rows, fused=True)
-    else:
-        hit, cache_slot = cache_probe(u.uniq, u.uvalid, hot_keys)
-    use_l2 = l2_keys is not None and l2_keys.shape[0] > 0
-    if use_l2:
-        if fused:
-            l2_hit, l2_slot, l2_probe_rows = ops.tier_probe(
-                u.uniq, u.uvalid & ~hit, l2_keys, l2_rows, fused=True)
-        else:
-            l2_hit, l2_slot = cache_probe(u.uniq, u.uvalid & ~hit, l2_keys)
-        miss = u.uvalid & ~hit & ~l2_hit
-    else:
-        l2_hit, l2_slot = None, None
-        miss = u.uvalid & ~hit
-    r = partition(u.uniq, miss, rps, world, capacity)
+    with obs.scope(obs.UNIQUE):
+        u = fixed_unique(ids, sentinel=rows_padded)
+    (hit, cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows,
+     miss) = _probe_tiers(u, hot_keys, hot_rows, l2_keys, l2_rows, fused)
+    with obs.scope(obs.PARTITION):
+        r = partition(u.uniq, miss, rps, world, capacity)
+        send_ids = jnp.full((world * capacity,), -1, jnp.int32)
+        send_ids = send_ids.at[r.send_slot].set(u.uniq.astype(jnp.int32),
+                                                mode="drop")
 
     # ---- Shuffle: route miss ids to owners --------------------------------
-    send_ids = jnp.full((world * capacity,), -1, jnp.int32)
-    send_ids = send_ids.at[r.send_slot].set(u.uniq.astype(jnp.int32), mode="drop")
     recv_ids = _a2a(send_ids.reshape(world, capacity), axes)
 
-    my = lax.axis_index(axes)
-    base = my.astype(jnp.int32) * rps
-    recv_valid = recv_ids >= 0
-    recv_local = jnp.clip(recv_ids - base, 0, rps - 1)
-
     # ---- local Gather (narrow width on the wire) ---------------------------
-    served = jnp.take(table_shard, recv_local.reshape(-1), axis=0)
-    served = served * recv_valid.reshape(-1, 1).astype(served.dtype)
+    with obs.scope(obs.GATHER):
+        my = lax.axis_index(axes)
+        base = my.astype(jnp.int32) * rps
+        recv_valid = recv_ids >= 0
+        recv_local = jnp.clip(recv_ids - base, 0, rps - 1)
+        served = jnp.take(table_shard, recv_local.reshape(-1), axis=0)
+        served = served * recv_valid.reshape(-1, 1).astype(served.dtype)
 
     # ---- Shuffle back + fused gather+project Stitch ------------------------
     back = _a2a(served.reshape(world, capacity, nd), axes).reshape(
         world * capacity, nd)
-    take_idx = jnp.minimum(r.send_slot, world * capacity - 1)
-    miss_rows, narrow = ops.gather_project(back, take_idx, r.kept, proj,
-                                           fused=fused)
-
-    if use_l2:
-        l2v = l2_probe_rows if fused else jnp.take(l2_rows, l2_slot, axis=0)
-        miss_rows = jnp.where(l2_hit[:, None], l2v.astype(miss_rows.dtype),
-                              miss_rows)
-    if probe_l1:
-        rows_u = jnp.where(hit[:, None], l1_probe_rows.astype(miss_rows.dtype),
-                           miss_rows)
-    elif hot_rows is not None and hot_rows.shape[0] > 0:
-        hot = jnp.take(hot_rows, cache_slot, axis=0)
-        rows_u = jnp.where(hit[:, None], hot.astype(miss_rows.dtype), miss_rows)
-    else:
-        rows_u = miss_rows
+    with obs.scope(obs.STITCH):
+        take_idx = jnp.minimum(r.send_slot, world * capacity - 1)
+        miss_rows, narrow = ops.gather_project(back, take_idx, r.kept, proj,
+                                               fused=fused)
+        rows_u = _stitch_tiers(miss_rows, hit, cache_slot, hot_rows,
+                               l1_probe_rows, l2_hit, l2_slot, l2_rows,
+                               l2_probe_rows)
 
     ctx = LookupCtx(
         uniq=u.uniq, inv=u.inv, uvalid=u.uvalid, hit=hit, cache_slot=cache_slot,
         routing=r, recv_ids=recv_ids, recv_local=recv_local, recv_valid=recv_valid,
-        l2_hit=l2_hit, l2_slot=l2_slot, narrow_rows=narrow,
+        l2_hit=l2_hit, l2_slot=l2_slot, narrow_rows=narrow, n_uniq=u.n_uniq,
     )
     return rows_u, ctx
 
@@ -378,8 +394,9 @@ def _dedup_apply(w_shard: jnp.ndarray, acc_shard: jnp.ndarray,
     adagrad + in-place scatter; reference accumulation order, ~1 ULP);
     ``False`` the argsort/segment_sum/scatter chain — both via
     ``ops.dedup_adagrad``."""
-    return ops.dedup_adagrad(w_shard, acc_shard, idx, g, valid, lr, eps,
-                             fused=fused)
+    with obs.scope(obs.MASTER_UPDATE):
+        return ops.dedup_adagrad(w_shard, acc_shard, idx, g, valid, lr, eps,
+                                 fused=fused)
 
 
 class CacheState(NamedTuple):
@@ -523,10 +540,11 @@ def _psum_into_tier(tier: CacheState, hit_mask, slot, g_u, axes: Axes,
     del fused
     h = tier.keys.shape[0]
     d = g_u.shape[1]
-    g_hit = g_u * hit_mask[:, None].astype(g_u.dtype)
-    g_hot = jnp.zeros((h, d), g_u.dtype).at[slot].add(g_hit)
-    g_hot = lax.psum(g_hot, axes)
-    return _tier_adagrad(tier, g_hot, lr, eps)
+    with obs.scope(obs.TIER_UPDATE):
+        g_hit = g_u * hit_mask[:, None].astype(g_u.dtype)
+        g_hot = jnp.zeros((h, d), g_u.dtype).at[slot].add(g_hit)
+        g_hot = lax.psum(g_hot, axes)
+        return _tier_adagrad(tier, g_hot, lr, eps)
 
 
 def _allgather_into_tier(tier: CacheState, hit_mask, slot, g_u, axes: Axes,
@@ -544,17 +562,19 @@ def _allgather_into_tier(tier: CacheState, hit_mask, slot, g_u, axes: Axes,
     accumulation happens in sorted-slot order, replica-identical)."""
     h = tier.keys.shape[0]
     d = g_u.shape[1]
-    g_hit = g_u * hit_mask[:, None].astype(g_u.dtype)
-    slots = jnp.where(hit_mask, slot, h).astype(jnp.int32)  # h = drop
-    all_slots = lax.all_gather(slots, axes, tiled=True)      # [world*n]
-    all_g = lax.all_gather(g_hit, axes, tiled=True)          # [world*n, D]
-    if fused:
-        rows2, acc2 = ops.dedup_adagrad(
-            tier.rows, tier.acc, all_slots, all_g, all_slots < h, lr, eps,
-            fused=True)
-        return CacheState(tier.keys, rows2, acc2)
-    g_hot = jnp.zeros((h, d), g_u.dtype).at[all_slots].add(all_g, mode="drop")
-    return _tier_adagrad(tier, g_hot, lr, eps)
+    with obs.scope(obs.TIER_UPDATE):
+        g_hit = g_u * hit_mask[:, None].astype(g_u.dtype)
+        slots = jnp.where(hit_mask, slot, h).astype(jnp.int32)  # h = drop
+        all_slots = lax.all_gather(slots, axes, tiled=True)      # [world*n]
+        all_g = lax.all_gather(g_hit, axes, tiled=True)          # [world*n, D]
+        if fused:
+            rows2, acc2 = ops.dedup_adagrad(
+                tier.rows, tier.acc, all_slots, all_g, all_slots < h, lr, eps,
+                fused=True)
+            return CacheState(tier.keys, rows2, acc2)
+        g_hot = jnp.zeros((h, d), g_u.dtype).at[all_slots].add(all_g,
+                                                               mode="drop")
+        return _tier_adagrad(tier, g_hot, lr, eps)
 
 
 def apply_sparse_grads_l2(
@@ -705,8 +725,9 @@ def count_frequencies(counts_shard: jnp.ndarray, ctx: LookupCtx) -> jnp.ndarray:
     the uncounted resident mass would otherwise decay below the routed tail
     and the flush would churn-evict genuinely hot rows.
     """
-    return counts_shard.at[ctx.recv_local.reshape(-1)].add(
-        ctx.recv_valid.reshape(-1).astype(counts_shard.dtype))
+    with obs.scope(obs.COUNT_FREQUENCIES):
+        return counts_shard.at[ctx.recv_local.reshape(-1)].add(
+            ctx.recv_valid.reshape(-1).astype(counts_shard.dtype))
 
 
 def count_hit_frequencies(counts_shard: jnp.ndarray, ctx: LookupCtx,
@@ -723,12 +744,14 @@ def count_hit_frequencies(counts_shard: jnp.ndarray, ctx: LookupCtx,
     at world=1, ranking-preserving in expectation at scale.
     """
     rps = counts_shard.shape[0]
-    my = lax.axis_index(axes).astype(jnp.int32)
-    local = ctx.uniq.astype(jnp.int32) - my * rps
-    ok = hit_mask & (local >= 0) & (local < rps)
-    safe = jnp.where(ok, jnp.clip(local, 0, rps - 1), rps)
-    inc = jnp.asarray(world, counts_shard.dtype) * ok.astype(counts_shard.dtype)
-    return counts_shard.at[safe].add(inc, mode="drop")
+    with obs.scope(obs.COUNT_FREQUENCIES):
+        my = lax.axis_index(axes).astype(jnp.int32)
+        local = ctx.uniq.astype(jnp.int32) - my * rps
+        ok = hit_mask & (local >= 0) & (local < rps)
+        safe = jnp.where(ok, jnp.clip(local, 0, rps - 1), rps)
+        inc = (jnp.asarray(world, counts_shard.dtype)
+               * ok.astype(counts_shard.dtype))
+        return counts_shard.at[safe].add(inc, mode="drop")
 
 
 def cache_hit_count(ctx: LookupCtx) -> jnp.ndarray:

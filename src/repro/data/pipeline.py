@@ -5,7 +5,9 @@ a deep enough prefetch queue plus *backup batches*: if the generator thread
 misses its deadline (slow remote read / skewed shard), the iterator yields
 the most recent spare instead of stalling the whole synchronous step. A
 producer that fails is never mistaken for the end of the stream: its error
-reaches the consumer.
+reaches the consumer. The consumer's wait (``batch_wait``) and the
+producer's make and device put (``batch_make``, ``batch_put``) are host
+spans on the profiler's timeline (``repro.obs``).
 """
 from __future__ import annotations
 
@@ -15,6 +17,10 @@ import time
 from typing import Any, Callable, Dict, Iterator, Optional
 
 import jax
+
+from repro import obs
+
+_END = object()
 
 
 class BatchProducerError(RuntimeError):
@@ -38,10 +44,14 @@ class Prefetcher:
 
     def _work(self):
         try:
-            for item in self.gen:
-                if self._stop:
+            gen = iter(self.gen)
+            while True:
+                with obs.span(obs.BATCH_MAKE):
+                    item = next(gen, _END)
+                if item is _END or self._stop:
                     return
-                out = self.put_fn(item)
+                with obs.span(obs.BATCH_PUT):
+                    out = self.put_fn(item)
                 # bounded put that stays responsive to close(): a blocking
                 # q.put() on a full queue would never observe _stop and the
                 # worker thread would hang forever after close()
@@ -66,6 +76,10 @@ class Prefetcher:
         late past ``timeout_s`` yields the last batch again (straggler
         mitigation), or raises ``TimeoutError`` when there is none yet. Only
         a generator that ended normally ends the iteration."""
+        with obs.span(obs.BATCH_WAIT):
+            return self._next()
+
+    def _next(self):
         deadline = time.monotonic() + self.timeout_s
         while True:
             try:

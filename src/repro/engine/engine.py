@@ -54,7 +54,8 @@ Invariants the engine maintains (and the tests pin down):
   the plan carries one, else compiles a fresh assignment and records it on
   the plan; any other registry name broadcasts to every group.
 
-Metrics are per-strategy-class sums (``overflow/<name>``,
+Metrics are the totals ``overflow``, ``cache_hits`` and ``distinct_ids``,
+per-strategy-class sums (``overflow/<name>``,
 ``cache_hits/<name>``) when a plan mixes classes, plus any strategy-declared
 per-tier keys (``cache_hits/l1`` / ``cache_hits/l2`` for ``picasso_l2``) —
 ``metric_keys`` is static so callers can build shard_map out_specs from it.
@@ -69,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import packed_embedding as pe
 from repro.core.assign import StrategySpec, resolve_assignment
 from repro.kernels import ops
@@ -227,7 +229,7 @@ class EmbeddingEngine:
     def metric_keys(self) -> Tuple[str, ...]:
         """Static metric pytree keys ``backward`` emits (callers build
         shard_map out_specs from this)."""
-        keys = ["overflow", "cache_hits"]
+        keys = ["overflow", "cache_hits", "distinct_ids"]
         if len(self.strategy_names) > 1:
             keys += [f"overflow/{n}" for n in self.strategy_names]
             keys += [f"cache_hits/{n}" for n in self.strategy_names]
@@ -264,23 +266,26 @@ class EmbeddingEngine:
                 packed: Dict[int, PackedBatch]
                 ) -> Tuple[Dict[int, jnp.ndarray], EngineContext]:
         """Packed batch -> pooled group outputs ``[B, n_bags, D]`` + ctx."""
-        rows, ctxs = self._wave_lookups(emb, packed)
-        pooled = {}
-        for gid, pb in packed.items():
-            g = self.plan.group(gid)
-            b = pb.ids.shape[0] // g.ids_per_sample
-            p = pe.pool(rows[gid], ctxs[gid].inv, pb.weights, pb.seg,
-                        b * g.n_bags, fused=self.use_fused)
-            pooled[gid] = p.reshape(b, g.n_bags, g.dim)
+        with obs.scope(obs.SPARSE_LOOKUP):
+            rows, ctxs = self._wave_lookups(emb, packed)
+            pooled = {}
+            for gid, pb in packed.items():
+                g = self.plan.group(gid)
+                b = pb.ids.shape[0] // g.ids_per_sample
+                with obs.scope(obs.POOL):
+                    p = pe.pool(rows[gid], ctxs[gid].inv, pb.weights, pb.seg,
+                                b * g.n_bags, fused=self.use_fused)
+                pooled[gid] = p.reshape(b, g.n_bags, g.dim)
         return pooled, EngineContext(ctxs=ctxs, packed=dict(packed))
 
     def lookup_rows(self, emb: Dict[str, EmbeddingState], gid: int,
                     ids: jnp.ndarray) -> jnp.ndarray:
         """Raw per-id rows ``[n, D]`` for one group (retrieval towers)."""
-        rows_u, ctx = self.strategies[gid].lookup(
-            emb[str(gid)], gid, ids, cache_on=self.cache_on[gid],
-            l2_on=self.l2_on[gid])
-        return jnp.take(rows_u, ctx.inv, axis=0)
+        with obs.scope(obs.SPARSE_LOOKUP):
+            rows_u, ctx = self.strategies[gid].lookup(
+                emb[str(gid)], gid, ids, cache_on=self.cache_on[gid],
+                l2_on=self.l2_on[gid])
+            return jnp.take(rows_u, ctx.inv, axis=0)
 
     # ------------------------------------------------------------ backward
     def backward(self, emb: Dict[str, EmbeddingState], ctx: EngineContext,
@@ -293,12 +298,19 @@ class EmbeddingEngine:
         g_pooled[seg[i]]``. Metrics are per-shard sums; callers psum them.
         With a mixed assignment, ``overflow/<name>`` and ``cache_hits/<name>``
         break the totals down per strategy class (see ``metric_keys``).
+        ``distinct_ids`` counts the distinct ids the groups' lookups worked
+        on (``LookupStrategy.distinct_ids``).
         """
+        with obs.scope(obs.SPARSE_UPDATE):
+            return self._backward(emb, ctx, g_pooled)
+
+    def _backward(self, emb, ctx, g_pooled):
         emb = dict(emb)
         zero = jnp.zeros((), jnp.int32)
         ovf = {n: zero for n in self.strategy_names}
         hits = {n: zero for n in self.strategy_names}
         extra = {k: zero for k in self._extra_keys}
+        distinct = zero
         for gid, g_p in g_pooled.items():
             pb = ctx.packed[gid]
             gctx = ctx.ctxs[gid]
@@ -307,18 +319,22 @@ class EmbeddingEngine:
             # transpose of the pool: one fused segment-grad pass produces the
             # [n_unique, D] row grads directly (no [n, D] per-id intermediate
             # when fused — see ops.segment_grad)
-            g_rows = ops.segment_grad(g_flat, pb.seg, pb.weights, gctx.inv,
-                                      pb.ids.shape[0], fused=self.use_fused)
+            with obs.scope(obs.SEGMENT_GRAD):
+                g_rows = ops.segment_grad(g_flat, pb.seg, pb.weights,
+                                          gctx.inv, pb.ids.shape[0],
+                                          fused=self.use_fused)
             st2, o, h = self.strategies[gid].apply_grads(
                 emb[str(gid)], gid, gctx, g_rows, cache_on=self.cache_on[gid],
                 l2_on=self.l2_on[gid])
             emb[str(gid)] = st2
             ovf[name] = ovf[name] + o
             hits[name] = hits[name] + h
+            distinct = distinct + self.strategies[gid].distinct_ids(gctx)
             for k, v in self.strategies[gid].tier_metrics(gctx).items():
                 extra[k] = extra[k] + v
         metrics = {"overflow": sum(ovf.values(), zero),
-                   "cache_hits": sum(hits.values(), zero)}
+                   "cache_hits": sum(hits.values(), zero),
+                   "distinct_ids": distinct}
         if len(self.strategy_names) > 1:
             for n in self.strategy_names:
                 metrics[f"overflow/{n}"] = ovf[n]
@@ -334,6 +350,10 @@ class EmbeddingEngine:
         tier get the two-tier flush: both tiers written back (psum mode),
         then one global frequency ranking refills L1 (top-H1) and L2
         (next-H2) disjointly."""
+        with obs.scope(obs.FLUSH):
+            return self._flush(emb)
+
+    def _flush(self, emb):
         out = dict(emb)
         for g in self.plan.groups:
             if not self.cache_on.get(g.gid, False):

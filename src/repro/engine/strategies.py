@@ -155,6 +155,11 @@ class LookupStrategy:
         raise NotImplementedError
 
     # ------------------------------------------------------------- metrics
+    def distinct_ids(self, ctx: Any) -> jnp.ndarray:
+        """The distinct ids this lookup worked on after its dedup (an int32
+        scalar); zero for a strategy that does not dedup."""
+        return jnp.zeros((), jnp.int32)
+
     def tier_metrics(self, ctx: Any) -> Dict[str, jnp.ndarray]:
         """Per-tier counters for this lookup, keyed by ``extra_metric_keys``.
 
@@ -198,6 +203,9 @@ class PicassoStrategy(LookupStrategy):
                              l2=st.l2)  # preserve an (unused) L2 tier as-is
         return (st2, ctx.routing.overflow.astype(jnp.int32),
                 pe.cache_hit_count(ctx).astype(jnp.int32))
+
+    def distinct_ids(self, ctx):
+        return ctx.n_uniq
 
 
 @register_strategy("hybrid")
@@ -429,6 +437,7 @@ class AllGatherCtx(NamedTuple):
 
     inv: jnp.ndarray    # [n] position -> unique slot
     uniq: jnp.ndarray   # [n] sorted unique ids (sentinel-padded)
+    n_uniq: jnp.ndarray  # scalar
 
 
 @register_strategy("allgather_rows")
@@ -450,7 +459,7 @@ class AllGatherRowsStrategy(LookupStrategy):
         rps = st.w.shape[0]
         u = pe.fixed_unique(ids, sentinel=rps * self.world)
         rows = pe.ps_lookup(st.w, u.uniq, axes=self.axes, world=self.world)
-        return rows, AllGatherCtx(inv=u.inv, uniq=u.uniq)
+        return rows, AllGatherCtx(inv=u.inv, uniq=u.uniq, n_uniq=u.n_uniq)
 
     def apply_grads(self, st, gid, ctx, g_rows, *, cache_on=False, l2_on=False):
         rps = st.w.shape[0]
@@ -467,3 +476,6 @@ class AllGatherRowsStrategy(LookupStrategy):
                                    fused=self.use_fused)
         zero = jnp.zeros((), jnp.int32)
         return st._replace(w=w2, acc=acc2), zero, zero
+
+    def distinct_ids(self, ctx):
+        return ctx.n_uniq

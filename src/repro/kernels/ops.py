@@ -124,6 +124,13 @@ def _kernel_on(op: str, fused: Optional[bool] = None) -> bool:
     return force if fused is None else bool(fused)
 
 
+def runs_kernel(op: str, fused: Optional[bool] = None) -> bool:
+    """Whether a call of ``op`` with this ``fused`` override runs its Pallas
+    kernel here (else its reference chain): for a caller that places a
+    kernel's fused work apart from the reference chain's steps."""
+    return _kernel_on(op, fused)
+
+
 def dispatch_lines(fused: Optional[bool] = None) -> List[str]:
     """The backend, then one line per op saying what it runs in this
     process — the table the launchers print at startup. ``fused`` is the

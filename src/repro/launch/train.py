@@ -17,9 +17,12 @@ import time
 def main(argv=None, cfg=None):
     """Parse ``argv`` (default ``sys.argv[1:]``), train, and return
     ``{"history": [...], "batches": {...}}``: one ``{step, loss, cache_hits,
-    overflow, seconds}`` entry per logged step (``seconds`` is the wall time
-    per step since the previous log line, first step's compile included),
-    and the batch prefetcher's ``produced``/``backup_served`` counters."""
+    distinct_ids, overflow, seconds}`` entry per logged step
+    (``distinct_ids`` counts the distinct ids the step looked up, summed over
+    chips, and ``cache_hits`` those of them the cache tiers served;
+    ``seconds`` is the wall time per step since the previous log line, first
+    step's compile included), and the batch prefetcher's
+    ``produced``/``backup_served`` counters."""
     # registry import is jax-importing but backend-lazy: XLA_FLAGS set after
     # parsing (for --devices) is still honoured at first device query.
     from repro.engine import AUTO_NAMES, available_strategies
@@ -157,6 +160,14 @@ def main(argv=None, cfg=None):
                     help="synthetic stream with a learnable CTR signal "
                          "(default: random labels) — smoke/CI runs assert "
                          "loss decrease on this")
+    ap.add_argument("--trace-dir", default="", metavar="DIR",
+                    help="write a jax.profiler trace of the first whole "
+                         "flush period after warm-up (the steps after the "
+                         "first tier flush, up to and including the next "
+                         "one) into DIR: the device planes with each "
+                         "operation's phase in its op_name, and the host "
+                         "spans of the batch pipeline, checkpoints and "
+                         "publishes (repro.obs)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
@@ -179,6 +190,7 @@ def main(argv=None, cfg=None):
     import jax
     import numpy as np
 
+    from repro import obs
     from repro.launch.cache import enable_compile_cache
     enable_compile_cache()
 
@@ -267,7 +279,8 @@ def main(argv=None, cfg=None):
         def timed(state, batch):
             t0 = time.perf_counter()
             out = fn(state, batch)
-            jax.block_until_ready(out[1]["loss"])
+            with obs.span(obs.REPLAN_TIMER):
+                jax.block_until_ready(out[1]["loss"])
             if replanner is not None:
                 replanner.observe_timing((time.perf_counter() - t0) * 1e6)
             return out
@@ -348,20 +361,57 @@ def main(argv=None, cfg=None):
     history = []
     t_log, step_log = time.perf_counter(), 0  # time and step of the last log
 
+    # --trace-dir: the profiler runs once, from the end of the first flush
+    # step after warm-up to the end of the next flush step (a rollback that
+    # replays these steps does not trace them again)
+    fi = plan.flush_iters
+    trace_from = -(-max(plan.warmup_iters, 1) // fi) * fi
+    tracing = traced = False
+    last_step = 0
+
+    def trace_control(step, m):
+        nonlocal tracing, traced, last_step
+        last_step = step
+        if step == trace_from and not traced:
+            jax.block_until_ready(m)
+            jax.profiler.start_trace(args.trace_dir)
+            tracing = traced = True
+        elif step == trace_from + fi and tracing:
+            jax.block_until_ready(m)
+            jax.profiler.stop_trace()
+            tracing = False
+            print(f"[train] trace of steps {trace_from + 1}-{step} in "
+                  f"{args.trace_dir}", flush=True)
+
+    def finish_trace():
+        """Stop a trace the run's end, or an error, cut short."""
+        if tracing:
+            jax.profiler.stop_trace()
+            print(f"[train] the run ended inside the traced flush period; "
+                  f"trace of steps {trace_from + 1}-{last_step} in "
+                  f"{args.trace_dir}")
+        elif args.trace_dir and not traced:
+            print(f"[train] no trace: the run ended before step {trace_from}, "
+                  "the first flush after warm-up")
+
     def on_metrics(step, m):
         nonlocal t_log, step_log
+        if args.trace_dir:
+            trace_control(step, m)
         if replanner is not None:
             replanner.observe(m)
         if step % args.log_every == 0:
             rec = {"step": step, "loss": float(m["loss"]),
                    "cache_hits": int(m["cache_hits"]),
+                   "distinct_ids": int(m["distinct_ids"]),
                    "overflow": int(m["overflow"])}
             now = time.perf_counter()  # float(loss) above waited for the step
             rec["seconds"] = (now - t_log) / max(step - step_log, 1)
             t_log, step_log = now, step
             history.append(rec)
             print(f"  step {step:5d} loss={rec['loss']:.4f} "
-                  f"hits={rec['cache_hits']} ovf={rec['overflow']} "
+                  f"hits={rec['cache_hits']}/{rec['distinct_ids']} "
+                  f"ovf={rec['overflow']} "
                   f"{rec['seconds'] * 1e3:.1f}ms/step", flush=True)
         if chaos is not None:
             if args.ckpt_dir:
@@ -429,113 +479,118 @@ def main(argv=None, cfg=None):
             state = pin_l2_to_host(state, mesh)
         return state, True
 
-    if args.stream:
-        # streaming driver: segments over the unbounded stream (--steps is
-        # ignored); each segment boundary checkpoints, publishes, and may
-        # apply the in-place reshard — no restart anywhere in the lifecycle
-        ckpt = AsyncCheckpointer(args.ckpt_dir) if args.ckpt_dir else None
-        active_ckpt = ckpt
-        start = 0
-        if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
-            state, start = restore_elastic(
-                args.ckpt_dir, plan, state, mesh=mesh, axes=axes,
-                log=lambda s: print(f"[train] elastic {s}", flush=True))
+    try:
+        if args.stream:
+            # streaming mode: segments over the unbounded stream (--steps is
+            # ignored); each segment boundary checkpoints, publishes, and may
+            # apply the in-place reshard — no restart anywhere in the lifecycle
+            ckpt = AsyncCheckpointer(args.ckpt_dir) if args.ckpt_dir else None
+            active_ckpt = ckpt
+            start = 0
+            if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
+                state, start = restore_elastic(
+                    args.ckpt_dir, plan, state, mesh=mesh, axes=axes,
+                    log=lambda s: print(f"[train] elastic {s}", flush=True))
+                stream.seek(start)  # resume replays from the exact batch index
+                print(f"[train] stream resumed at step {start}", flush=True)
+
+            publisher = None
+            if args.publish_dir:
+                def publisher(step, state):
+                    with obs.span(obs.PUBLISH):
+                        publish_state(args.publish_dir, step, state,
+                                      meta=plan_meta(plan))
+                    print(f"[stream] published step {step} -> {args.publish_dir}",
+                          flush=True)
+                    if chaos is not None:
+                        chaos.after_publish(step, args.publish_dir)
+
+            def on_segment(seg, step, state):
+                if reshard_pending and step >= args.reshard_at:
+                    state = do_reshard(state, step)
+                    return state, step_fn, stream
+                return None
+
+            state, last = run_stream(
+                state, step_fn, stream,
+                segment_steps=args.segment_steps,
+                n_segments=args.stream_segments, start_step=start,
+                checkpointer=ckpt, meta_fn=lambda: plan_meta(plan),
+                publisher=publisher, on_metrics=on_metrics,
+                on_segment=on_segment)
+            if ckpt is not None:
+                ckpt.wait()
+            batches = source.stats
+            stream.close()
+            print(f"[train] batches {batches}")
+            print(f"[train] stream done at step {last} (world={world})")
+            return {"history": history, "batches": batches}
+
+        if args.ckpt_dir:
+            sup = Supervisor(args.ckpt_dir, ckpt_every=args.ckpt_every,
+                             shardings=cur_shardings)
+            active_ckpt = sup.ckpt
+            # keep the plan sidecar on EVERY checkpoint: it records the world/
+            # mesh the state was written under (elastic-restore detection) and —
+            # for replanned runs — the plan revision; dropping it would make the
+            # NEXT resume restore revision-shaped tiers into the seed-plan
+            # template or shape-error on a world change
+            sup.meta = plan_meta(plan)
+            if meta is not None and int(meta.get("world", world)) != world:
+                # checkpoint written at a different world size: route the restore
+                # through the exact resharding path instead of the stale template
+                state, start = restore_elastic(
+                    args.ckpt_dir, plan, state, mesh=mesh, axes=axes,
+                    log=lambda s: print(f"[train] elastic {s}", flush=True))
+            else:
+                state, start = sup.maybe_restore(state)
             stream.seek(start)  # resume replays from the exact batch index
-            print(f"[train] stream resumed at step {start}", flush=True)
-
-        publisher = None
-        if args.publish_dir:
-            def publisher(step, state):
-                publish_state(args.publish_dir, step, state,
-                              meta=plan_meta(plan))
-                print(f"[stream] published step {step} -> {args.publish_dir}",
-                      flush=True)
-                if chaos is not None:
-                    chaos.after_publish(step, args.publish_dir)
-
-        def on_segment(seg, step, state):
-            if reshard_pending and step >= args.reshard_at:
-                state = do_reshard(state, step)
-                return state, step_fn, stream
-            return None
-
-        state, last = run_stream(
-            state, step_fn, stream,
-            segment_steps=args.segment_steps,
-            n_segments=args.stream_segments, start_step=start,
-            checkpointer=ckpt, meta_fn=lambda: plan_meta(plan),
-            publisher=publisher, on_metrics=on_metrics,
-            on_segment=on_segment)
-        if ckpt is not None:
-            ckpt.wait()
-        batches = source.stats
-        stream.close()
-        print(f"[train] batches {batches}")
-        print(f"[train] stream done at step {last} (world={world})")
-        return {"history": history, "batches": batches}
-
-    if args.ckpt_dir:
-        sup = Supervisor(args.ckpt_dir, ckpt_every=args.ckpt_every,
-                         shardings=cur_shardings)
-        active_ckpt = sup.ckpt
-        # keep the plan sidecar on EVERY checkpoint: it records the world/
-        # mesh the state was written under (elastic-restore detection) and —
-        # for replanned runs — the plan revision; dropping it would make the
-        # NEXT resume restore revision-shaped tiers into the seed-plan
-        # template or shape-error on a world change
-        sup.meta = plan_meta(plan)
-        if meta is not None and int(meta.get("world", world)) != world:
-            # checkpoint written at a different world size: route the restore
-            # through the exact resharding path instead of the stale template
-            state, start = restore_elastic(
-                args.ckpt_dir, plan, state, mesh=mesh, axes=axes,
-                log=lambda s: print(f"[train] elastic {s}", flush=True))
-        else:
-            state, start = sup.maybe_restore(state)
-        stream.seek(start)  # resume replays from the exact batch index
-        step = start
-        # known limitation: a failure-restore *inside* a segment replays the
-        # restored window without re-hitting an already-passed replan
-        # boundary (the plan itself stays consistent — post-migration
-        # checkpoints are written eagerly — but the replayed steps are folded
-        # into the Replanner's metric window a second time, and the next
-        # replan happens at the segment end rather than mid-replay)
-        while step < args.steps:
-            seg_end = next_boundary(step)
-            state = sup.run(state, step_fn, stream, seg_end, start_step=step,
-                            on_metrics=on_metrics, shardings=cur_shardings)
-            step = seg_end
-            if reshard_pending and step >= args.reshard_at \
-                    and step < args.steps:
-                state = do_reshard(state, step)
-                # durable, mesh-consistent restore point: a later failure
-                # must restore post-reshard row counts + the new world meta
-                sup.meta = plan_meta(plan)
-                sup.ckpt.save(step, state, meta=sup.meta)
-                sup.ckpt.wait()
-            if replanner is not None and step < args.steps:
-                state, migrated = do_replan(state, step)
-                if migrated:
-                    # durable, plan-consistent restore point: a mid-segment
-                    # failure must not restore pre-migration tier shapes
+            step = start
+            # known limitation: a failure-restore *inside* a segment replays the
+            # restored window without re-hitting an already-passed replan
+            # boundary (the plan itself stays consistent — post-migration
+            # checkpoints are written eagerly — but the replayed steps are folded
+            # into the Replanner's metric window a second time, and the next
+            # replan happens at the segment end rather than mid-replay)
+            while step < args.steps:
+                seg_end = next_boundary(step)
+                state = sup.run(state, step_fn, stream, seg_end, start_step=step,
+                                on_metrics=on_metrics, shardings=cur_shardings)
+                step = seg_end
+                if reshard_pending and step >= args.reshard_at \
+                        and step < args.steps:
+                    state = do_reshard(state, step)
+                    # durable, mesh-consistent restore point: a later failure
+                    # must restore post-reshard row counts + the new world meta
                     sup.meta = plan_meta(plan)
                     sup.ckpt.save(step, state, meta=sup.meta)
                     sup.ckpt.wait()
-    else:
-        it = iter(stream)
-        t_log = time.perf_counter()
-        for i in range(1, args.steps + 1):
-            batch = next(it, None)
-            if batch is None:  # the synthetic stream is endless
-                raise RuntimeError(f"batch stream ended before step {i}")
-            state, m = step_fn(state, batch)
-            on_metrics(i, m)
-            if reshard_pending and i >= args.reshard_at and i < args.steps:
-                state = do_reshard(state, i)
-                it = iter(stream)  # the Prefetcher was rebuilt for the new mesh
-            if (replanner is not None and i % args.replan_iters == 0
-                    and i < args.steps):
-                state, _ = do_replan(state, i)
+                if replanner is not None and step < args.steps:
+                    state, migrated = do_replan(state, step)
+                    if migrated:
+                        # durable, plan-consistent restore point: a mid-segment
+                        # failure must not restore pre-migration tier shapes
+                        sup.meta = plan_meta(plan)
+                        sup.ckpt.save(step, state, meta=sup.meta)
+                        sup.ckpt.wait()
+        else:
+            it = iter(stream)
+            t_log = time.perf_counter()
+            for i in range(1, args.steps + 1):
+                batch = next(it, None)
+                if batch is None:  # the synthetic stream is endless
+                    raise RuntimeError(f"batch stream ended before step {i}")
+                with obs.step_span(i):
+                    state, m = step_fn(state, batch)
+                on_metrics(i, m)
+                if reshard_pending and i >= args.reshard_at and i < args.steps:
+                    state = do_reshard(state, i)
+                    it = iter(stream)  # the Prefetcher was rebuilt for the new mesh
+                if (replanner is not None and i % args.replan_iters == 0
+                        and i < args.steps):
+                    state, _ = do_replan(state, i)
+    finally:
+        finish_trace()
     if replanner is not None:
         n_mig = sum(1 for e in replanner.events if e.migrated)
         print(f"[train] replans: {len(replanner.events)} attempted, "

@@ -35,6 +35,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 class AnomalyRollback(RuntimeError):
     """``k_rollback`` consecutive anomalous steps: the guard gives up on
@@ -124,9 +126,10 @@ class AnomalyGuard:
             raise RuntimeError("AnomalyGuard has no step bound; call rebind()")
         new_state, metrics = self._inner(state, batch)
         thr = self.threshold
-        loss = float(metrics["loss"])  # host sync: see module docstring
-        gn_m = metrics.get(self.cfg.metric)
-        gn = float(gn_m) if gn_m is not None else None
+        with obs.span(obs.GUARD_SYNC):
+            loss = float(metrics["loss"])  # host sync: see module docstring
+            gn_m = metrics.get(self.cfg.metric)
+            gn = float(gn_m) if gn_m is not None else None
         nonfinite = not np.isfinite(loss) or (gn is not None
                                               and not np.isfinite(gn))
         spike = (not nonfinite and gn is not None and thr > 0 and gn > thr)

@@ -23,6 +23,7 @@ import os
 from pathlib import Path
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
+from repro import obs
 from repro.embedding.state import reshard_state
 from repro.train.checkpoint import (CheckpointCorrupt, available_steps,
                                     restore_checkpoint, save_checkpoint)
@@ -57,7 +58,8 @@ def run_stream(state: Any, step_fn: Callable, batches: Iterable, *,
                 batch = next(it)
             except StopIteration:
                 break
-            state, m = step_fn(state, batch)
+            with obs.step_span(step + 1):
+                state, m = step_fn(state, batch)
             step += 1
             done += 1
             if on_metrics is not None:

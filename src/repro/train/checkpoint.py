@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 try:  # optional: plain .npy files when the container lacks zstandard
     import zstandard
 except ImportError:
@@ -390,8 +392,10 @@ class AsyncCheckpointer:
 
     def save(self, step: int, state: Any,
              meta: Optional[Dict[str, Any]] = None) -> None:
-        self.wait()
-        host_state = jax.device_get(state)  # synchronous snapshot, async write
+        with obs.span(obs.CHECKPOINT):
+            self.wait()
+            # synchronous snapshot, async write
+            host_state = jax.device_get(state)
 
         def work():
             self.last_path = save_checkpoint(self.ckpt_dir, step, host_state,

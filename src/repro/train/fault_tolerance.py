@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 
+from repro import obs
 from repro.train.checkpoint import AsyncCheckpointer, latest_step, restore_verified
 
 log = logging.getLogger("repro.ft")
@@ -141,7 +142,8 @@ class Supervisor:
                 if fail_injector is not None:
                     fail_injector(step)
                 batch = next(stream)
-                state, metrics = step_fn(state, batch)
+                with obs.step_span(step + 1):
+                    state, metrics = step_fn(state, batch)
                 step += 1
                 clean += 1
                 if self.failures and clean >= self.reset_after:

@@ -54,6 +54,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.features import PackedBatch, pack_group
 from repro.core.interleaving import pipeline_handoff, resolve_overlap
 from repro.core.packing import PicassoPlan
@@ -147,7 +148,9 @@ def make_train_step(model: WDLModel, plan: PicassoPlan, mesh, axes: Tuple[str, .
         emb: Dict[str, EmbeddingState] = dict(state["emb"])
         dense, opt, step = state["dense"], state["opt"], state["step"]
 
-        packed_full = {g.gid: pack_group(g, batch["fields"]) for g in plan.groups}
+        with obs.scope(obs.STEP_MISC):
+            packed_full = {g.gid: pack_group(g, batch["fields"])
+                           for g in plan.groups}
 
         def packed_micro(i):
             out = {}
@@ -189,39 +192,49 @@ def make_train_step(model: WDLModel, plan: PicassoPlan, mesh, axes: Tuple[str, .
                 # D-Interleaving: issue Shuffle of chunk i+1 before dense of i
                 pending = (engine.forward(emb, packed_micro(i + 1)),
                            batch_micro(i + 1))
-            (loss, _logits), (g_dense, g_pooled) = grad_fn(dense, pooled, mb)
-            loss_acc = loss_acc + loss
-            g_dense_acc = jax.tree.map(jnp.add, g_dense_acc, g_dense)
+            with obs.scope(obs.DENSE):
+                (loss, _logits), (g_dense, g_pooled) = grad_fn(dense, pooled, mb)
+                loss_acc = loss_acc + loss
+                g_dense_acc = jax.tree.map(jnp.add, g_dense_acc, g_dense)
             emb, em = engine.backward(emb, ectx, g_pooled)
-            em_acc = {k: em_acc[k] + em[k] for k in em_acc}
+            with obs.scope(obs.STEP_MISC):
+                em_acc = {k: em_acc[k] + em[k] for k in em_acc}
             if not use_overlap and not (tcfg.pipeline_micro) and i + 1 < n_micro:
                 pending = (engine.forward(emb, packed_micro(i + 1)),
                            batch_micro(i + 1))
 
         # ---- dense DP: psum grads over the whole mesh ----------------------
-        if tcfg.grad_compression != "none":
-            from repro.optim.grad_compression import compressed_psum
-            g_dense_acc, _ = compressed_psum(g_dense_acc, axes,
-                                             mode=tcfg.grad_compression)
-        else:
-            g_dense_acc = lax.psum(g_dense_acc, axes)
-        loss_glob = lax.psum(loss_acc, axes)
-        upd = adam_update if tcfg.optimizer == "adam" else lamb_update
-        dense2, opt2 = upd(dense, g_dense_acc, opt, tcfg.lr_dense)
+        with obs.scope(obs.DENSE):
+            if tcfg.grad_compression != "none":
+                from repro.optim.grad_compression import compressed_psum
+                g_dense_acc, _ = compressed_psum(g_dense_acc, axes,
+                                                 mode=tcfg.grad_compression)
+            else:
+                g_dense_acc = lax.psum(g_dense_acc, axes)
+            upd = adam_update if tcfg.optimizer == "adam" else lamb_update
+            dense2, opt2 = upd(dense, g_dense_acc, opt, tcfg.lr_dense)
 
         # ---- HybridHash flush (Algorithm 1 L23-26) -------------------------
         step2 = step + 1
         if engine.any_cache and tcfg.flush_in_step:
-            do_flush = (step2 >= plan.warmup_iters) & (step2 % plan.flush_iters == 0)
-            emb = lax.cond(do_flush, engine.flush, lambda e: e, emb)
+            # the cond itself is named too, so that its time on the device
+            # is the flush's on every step
+            with obs.scope(obs.FLUSH):
+                do_flush = ((step2 >= plan.warmup_iters)
+                            & (step2 % plan.flush_iters == 0))
+                emb = lax.cond(do_flush, engine.flush, lambda e: e, emb)
 
         new_state = {"emb": emb, "dense": dense2, "opt": opt2, "step": step2}
-        # global dense-gradient norm (g_dense_acc is already psum'd): the
-        # numeric health signal runtime.guard thresholds for spike rejection
-        grad_norm = jnp.sqrt(sum(jnp.vdot(g, g)
-                                 for g in jax.tree.leaves(g_dense_acc)))
-        metrics = {"loss": loss_glob, "step": step2, "grad_norm": grad_norm,
-                   **{k: lax.psum(em_acc[k], axes) for k in engine.metric_keys}}
+        with obs.scope(obs.STEP_MISC):
+            loss_glob = lax.psum(loss_acc, axes)
+            # global dense-gradient norm (g_dense_acc is already psum'd): the
+            # numeric health signal runtime.guard thresholds for spike
+            # rejection
+            grad_norm = jnp.sqrt(sum(jnp.vdot(g, g)
+                                     for g in jax.tree.leaves(g_dense_acc)))
+            metrics = {"loss": loss_glob, "step": step2, "grad_norm": grad_norm,
+                       **{k: lax.psum(em_acc[k], axes)
+                          for k in engine.metric_keys}}
         return new_state, metrics
 
     # ---------------------------------------------------------------- wrap

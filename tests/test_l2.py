@@ -447,13 +447,13 @@ def test_metric_keys_static_and_mixed(mesh1):
     plan = make_plan(_cfg64(), world=1, per_device_batch=GB,
                      hot_bytes=1 << 14, l2_bytes=320)
     eng = EmbeddingEngine(plan, AXES, 1, strategy="picasso_l2")
-    assert eng.metric_keys == ("overflow", "cache_hits",
+    assert eng.metric_keys == ("overflow", "cache_hits", "distinct_ids",
                                "cache_hits/l1", "cache_hits/l2")
     mixed_plan = make_plan(_mixed_cfg(), world=1, per_device_batch=GB,
                            hot_bytes=1 << 14, l2_bytes=1 << 18)
     meng = EmbeddingEngine(mixed_plan, AXES, 1, strategy="mixed")
     assert set(meng.metric_keys) == {
-        "overflow", "cache_hits",
+        "overflow", "cache_hits", "distinct_ids",
         "overflow/ps", "overflow/picasso_l2",
         "cache_hits/ps", "cache_hits/picasso_l2",
         "cache_hits/l1", "cache_hits/l2"}

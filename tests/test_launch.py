@@ -1,7 +1,9 @@
 """The training launcher as a process: a batch producer that dies fails the
-run, the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, and
-the id -> row map is the same in every process."""
+run, the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, the
+id -> row map is the same in every process, and ``--trace-dir`` profiles
+one flush period."""
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -12,14 +14,15 @@ ROOT = Path(__file__).resolve().parent.parent
 SMOKE = ["--arch", "deepfm", "--smoke", "--global-batch", "32", "--steps", "6",
          "--log-every", "1"]
 
-# the synthetic stream yields two batches, then its producer raises
+# the synthetic stream yields LIVE batches (two unless set), then its
+# producer raises
 DYING = """
-import itertools, sys
+import itertools, os, sys
 import repro.data.synthetic as syn
 real = syn.batch_stream
 
 def dying(*a, **kw):
-    yield from itertools.islice(real(*a, **kw), 2)
+    yield from itertools.islice(real(*a, **kw), int(os.environ.get("LIVE", 2)))
     raise OSError("batch source lost")
 
 syn.batch_stream = dying
@@ -109,3 +112,48 @@ def test_packed_ids_independent_of_hash_seed():
         assert out.returncode == 0, out.stderr[-2000:]
         outs.append(out.stdout.split()[-1])
     assert outs[0] == outs[1]
+
+
+def test_train_trace_dir_profiles_one_flush_period(tmp_path):
+    """``--trace-dir`` writes one profile of the first whole flush period
+    after warm-up (the smoke plan flushes every 20 steps from step 10, so
+    steps 21-40): one ``train`` step span each, and the batch pipeline's
+    host spans beside them."""
+    from jax.profiler import ProfileData
+
+    from repro import obs
+    args = ["--arch", "deepfm", "--smoke", "--global-batch", "32",
+            "--steps", "42", "--log-every", "10", "--trace-dir", str(tmp_path)]
+    out = _run("import sys\nfrom repro.launch.train import main\n"
+               "main(sys.argv[1:])", args)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert f"[train] trace of steps 21-40 in {tmp_path}" in out.stdout
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    assert len(found) == 1
+    names = {}
+    for p in ProfileData.from_file(str(found[0])).planes:
+        for line in p.lines:
+            for e in line.events:
+                names[e.name] = names.get(e.name, 0) + 1
+    assert names.get(obs.STEP) == 20
+    # the consumer waits once a step; the producer (a queue of depth 2) may
+    # have made up to 3 of the period's batches before the trace started,
+    # the two queued and the one in its hand, and may be inside a make or a
+    # put when the trace stops
+    assert names.get(obs.BATCH_WAIT) == 20
+    for span in (obs.BATCH_MAKE, obs.BATCH_PUT):
+        assert 20 - 3 - 1 <= names.get(span, 0) <= 21, span
+    assert re.search(r"step +40 loss=\S+ hits=\d+/[1-9]\d* ovf=", out.stdout)
+
+
+def test_train_trace_stops_when_the_run_fails_inside_it(tmp_path):
+    """A run that fails inside the traced flush period still stops the
+    profiler and writes its trace."""
+    args = ["--arch", "deepfm", "--smoke", "--global-batch", "32",
+            "--steps", "42", "--log-every", "10", "--trace-dir", str(tmp_path)]
+    out = _run(DYING, args, {"LIVE": "30"})
+    assert out.returncode != 0
+    assert "batch source lost" in out.stderr
+    assert ("[train] the run ended inside the traced flush period; trace of "
+            f"steps 21-") in out.stdout
+    assert len(list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))) == 1

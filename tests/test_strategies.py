@@ -155,12 +155,12 @@ def test_mixed_engine_per_group_dispatch_and_gating(mesh1):
     assert plan.cache_rows[gid_tiny] > 0 and plan.cache_rows[gid_big] > 0
     assert eng.cache_on == {gid_tiny: False, gid_big: True}
     assert eng.any_cache
-    assert set(eng.metric_keys) == {"overflow", "cache_hits",
+    assert set(eng.metric_keys) == {"overflow", "cache_hits", "distinct_ids",
                                     "overflow/ps", "overflow/picasso",
                                     "cache_hits/ps", "cache_hits/picasso"}
     # single-strategy engines keep the lean metric pytree
-    assert EmbeddingEngine(plan, AXES, 1).metric_keys == ("overflow",
-                                                          "cache_hits")
+    assert EmbeddingEngine(plan, AXES, 1).metric_keys == (
+        "overflow", "cache_hits", "distinct_ids")
 
 
 def test_mixed_flush_skips_uncached_groups(mesh1):
