@@ -21,7 +21,10 @@ is a local gather with zero ICI traffic. Hit gradients are psum'd (replicas
 stay bit-identical) and applied to the replicated hot tier; the hot tier is
 the authoritative storage for its rows between flushes, so training stays
 *exact* synchronous SGD — flush writes rows+optimizer state back to the owner
-shard and reloads the new top-k set.
+shard and reloads the new top-k set. The hot tier's keys change only at a
+flush, so it carries a rank index of them (``rank_index``, 8 bytes per 32
+global rows, replicated like the keys), rebuilt there: a probe is two
+gathers a query rather than a binary search over the keys.
 """
 from __future__ import annotations
 
@@ -29,6 +32,7 @@ from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro import obs
@@ -154,23 +158,78 @@ class LookupCtx(NamedTuple):
     #   (``UniqueResult.n_uniq``; None where the lookup does not dedup)
 
 
+def rank_words(rows_padded: int) -> int:
+    """Words of a tier's rank index over ``rows_padded`` global rows: one a
+    32 rows, and one more that the sentinel query ``rows_padded`` reads."""
+    return rows_padded // 32 + 1
+
+
+def rank_index(keys: jnp.ndarray, rows_padded: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The rank index of a tier's sorted, distinct ``keys``: ``bits[w]`` has
+    bit ``b`` set when row ``32w + b`` is a key, and ``base[w]`` counts the
+    keys below row ``32w`` (both ``[rank_words(rows_padded)]``, uint32 and
+    int32). Sentinel slots (``rows_padded``) set no bit. Built once when a
+    tier's keys are set; ``cache_probe`` then answers a query with two
+    gathers instead of a binary search."""
+    n_words = rank_words(rows_padded)
+    k = keys.astype(jnp.int32)
+    word = jnp.where(k < rows_padded, k >> 5, n_words)   # sentinels dropped
+    bit = jnp.left_shift(jnp.uint32(1), (k & 31).astype(jnp.uint32))
+    bits = jax.ops.segment_sum(bit, word, num_segments=n_words,
+                               indices_are_sorted=True)
+    count = lax.population_count(bits).astype(jnp.int32)
+    return bits, jnp.cumsum(count) - count
+
+
+def rank_index_np(keys: np.ndarray, rows_padded: int
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """``rank_index`` on the host, for tiers made in numpy."""
+    n_words = rank_words(rows_padded)
+    k = np.asarray(keys, np.int64)
+    k = k[k < rows_padded]
+    # distinct bits of one word sum exactly in float64 (all below 2**32)
+    bits = np.bincount(k >> 5, weights=np.left_shift(1, k & 31),
+                       minlength=n_words).astype(np.uint32)
+    count = np.bincount(k >> 5, minlength=n_words)
+    return bits, (np.cumsum(count) - count).astype(np.int32)
+
+
 def cache_probe(uniq: jnp.ndarray, uvalid: jnp.ndarray,
-                hot_keys: Optional[jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                hot_keys: Optional[jnp.ndarray],
+                index: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(hit, slot)`` of each query in a tier: ``slot`` is the clamped
+    ``searchsorted(hot_keys, uniq)``. With the tier's rank index
+    (``rank_index``) both are read from the query's word, else searched;
+    the answers agree on every row id and on the sentinel ``rows_padded``
+    of an invalid (``~uvalid``) slot."""
     if hot_keys is None or hot_keys.shape[0] == 0:
         z = jnp.zeros(uniq.shape, bool)
         return z, jnp.zeros(uniq.shape, jnp.int32)
-    p = jnp.searchsorted(hot_keys, uniq).astype(jnp.int32)
-    p_c = jnp.clip(p, 0, hot_keys.shape[0] - 1)
-    hit = (hot_keys[p_c] == uniq) & uvalid
-    return hit, p_c
+    if index is None:
+        p = jnp.searchsorted(hot_keys, uniq).astype(jnp.int32)
+        p_c = jnp.clip(p, 0, hot_keys.shape[0] - 1)
+        hit = (hot_keys[p_c] == uniq) & uvalid
+        return hit, p_c
+    bits, base = index
+    q = uniq.astype(jnp.int32)
+    w = jnp.clip(q >> 5, 0, bits.shape[0] - 1)
+    b = (q & 31).astype(jnp.uint32)
+    word = bits[w]
+    hit = (((word >> b) & 1) == 1) & uvalid
+    below = word & (jnp.left_shift(jnp.uint32(1), b) - 1)
+    p = base[w] + lax.population_count(below).astype(jnp.int32)
+    return hit, jnp.clip(p, 0, hot_keys.shape[0] - 1)
 
 
-def _probe_tiers(u: UniqueResult, hot_keys, hot_rows, l2_keys, l2_rows,
-                 fused: bool):
+def _probe_tiers(u: UniqueResult, hot_keys, hot_rows, hot_index, l2_keys,
+                 l2_rows, fused: bool):
     """The tiered probe, under the ``tier_probe`` scope: L1 (``hot_keys``)
     first, then the L2 tier for the L1 misses only. Returns ``(hit,
     cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows, miss)``,
-    ``l2_*`` None without an L2 tier.
+    ``l2_*`` None without an L2 tier. ``hot_index`` is the L1 tier's rank
+    index (``rank_index``) where it carries one; the L2 tier is searched.
 
     The probe is the search: which ids a tier holds, and at which slot. The
     hit rows are fetched by the Stitch (``_stitch_tiers``), except where the
@@ -184,7 +243,7 @@ def _probe_tiers(u: UniqueResult, hot_keys, hot_rows, l2_keys, l2_rows,
             hit, slot, l1_rows = ops.tier_probe(u.uniq, u.uvalid, hot_keys,
                                                 hot_rows, fused=True)
         else:
-            hit, slot = cache_probe(u.uniq, u.uvalid, hot_keys)
+            hit, slot = cache_probe(u.uniq, u.uvalid, hot_keys, hot_index)
             l1_rows = None
         if l2_keys is None or l2_keys.shape[0] == 0:
             return hit, slot, l1_rows, None, None, None, u.uvalid & ~hit
@@ -227,6 +286,7 @@ def mp_lookup(
     capacity: int,
     hot_keys: Optional[jnp.ndarray] = None,   # [H] replicated, sorted
     hot_rows: Optional[jnp.ndarray] = None,   # [H, D] replicated
+    hot_index: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # rank_index
     l2_keys: Optional[jnp.ndarray] = None,    # [H2] L2 host tier, sorted
     l2_rows: Optional[jnp.ndarray] = None,    # [H2, D] L2 host tier
     fused: bool = False,                      # fused tier-probe kernels
@@ -246,7 +306,9 @@ def mp_lookup(
     one ``ops.tier_probe`` kernel pass (binary search + hit-masked row
     gather) where that kernel runs (``_probe_tiers``); the probed rows come
     back zero-masked, so the Stitch below is a single ``where`` per tier and
-    hit values are identical either way.
+    hit values are identical either way. ``hot_index``, the L1 tier's
+    ``rank_index`` where it carries one, answers the L1 probe with two
+    gathers a query instead of the search, bit for bit the same.
     """
     rps, d = table_shard.shape
     rows_padded = rps * world
@@ -255,7 +317,8 @@ def mp_lookup(
     with obs.scope(obs.UNIQUE):
         u = fixed_unique(ids, sentinel=rows_padded)
     (hit, cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows,
-     miss) = _probe_tiers(u, hot_keys, hot_rows, l2_keys, l2_rows, fused)
+     miss) = _probe_tiers(u, hot_keys, hot_rows, hot_index, l2_keys, l2_rows,
+                          fused)
     with obs.scope(obs.PARTITION):
         r = partition(u.uniq, miss, rps, world, capacity)
         send_ids = jnp.full((world * capacity,), -1, jnp.int32)
@@ -302,6 +365,7 @@ def mp_lookup_narrow(
     capacity: int,
     hot_keys: Optional[jnp.ndarray] = None,   # [H1] sorted; tier rows are WIDE
     hot_rows: Optional[jnp.ndarray] = None,   # [H1, D]
+    hot_index: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # rank_index
     l2_keys: Optional[jnp.ndarray] = None,    # [H2] sorted
     l2_rows: Optional[jnp.ndarray] = None,    # [H2, D]
     fused: bool = False,
@@ -325,7 +389,8 @@ def mp_lookup_narrow(
     with obs.scope(obs.UNIQUE):
         u = fixed_unique(ids, sentinel=rows_padded)
     (hit, cache_slot, l1_probe_rows, l2_hit, l2_slot, l2_probe_rows,
-     miss) = _probe_tiers(u, hot_keys, hot_rows, l2_keys, l2_rows, fused)
+     miss) = _probe_tiers(u, hot_keys, hot_rows, hot_index, l2_keys, l2_rows,
+                          fused)
     with obs.scope(obs.PARTITION):
         r = partition(u.uniq, miss, rps, world, capacity)
         send_ids = jnp.full((world * capacity,), -1, jnp.int32)
@@ -403,6 +468,30 @@ class CacheState(NamedTuple):
     keys: jnp.ndarray   # [H] sorted global row ids (sentinel = rows_padded)
     rows: jnp.ndarray   # [H, D]
     acc: jnp.ndarray    # [H, 1] adagrad accumulator
+    # the rank index of ``keys`` (``rank_index``), carried by the L1 tier:
+    bits: Optional[jnp.ndarray] = None  # [rank_words] uint32
+    base: Optional[jnp.ndarray] = None  # [rank_words] int32
+
+    @property
+    def index(self) -> Optional[Tuple[jnp.ndarray, jnp.ndarray]]:
+        return None if self.bits is None else (self.bits, self.base)
+
+
+def with_rank_index(tier: CacheState, rows_padded: int) -> CacheState:
+    """``tier`` carrying the rank index of its keys (``rank_index``, or its
+    numpy twin for a tier made on the host). An empty tier carries none."""
+    if tier.keys.shape[0] == 0:
+        return tier
+    build = rank_index_np if isinstance(tier.keys, np.ndarray) else rank_index
+    bits, base = build(tier.keys, rows_padded)
+    return tier._replace(bits=bits, base=base)
+
+
+def _reindexed(new: CacheState, old: CacheState, rows_padded: int
+               ) -> CacheState:
+    """A flushed tier carries a rank index of its new keys iff the tier it
+    replaces carried one (so the flush keeps the state's structure)."""
+    return new if old.bits is None else with_rank_index(new, rows_padded)
 
 
 class ProjState(NamedTuple):
@@ -520,8 +609,8 @@ def _tier_adagrad(tier: CacheState, g_hot: jnp.ndarray, lr: float,
     touched = (jnp.abs(g_hot).max(axis=-1, keepdims=True) > 0).astype(gsq.dtype)
     acc_new = tier.acc + gsq * touched
     upd = lr * g_hot / jnp.sqrt(acc_new + eps)
-    return CacheState(tier.keys, tier.rows - upd.astype(tier.rows.dtype),
-                      acc_new.astype(tier.acc.dtype))
+    return tier._replace(rows=tier.rows - upd.astype(tier.rows.dtype),
+                         acc=acc_new.astype(tier.acc.dtype))
 
 
 def _psum_into_tier(tier: CacheState, hit_mask, slot, g_u, axes: Axes,
@@ -571,7 +660,7 @@ def _allgather_into_tier(tier: CacheState, hit_mask, slot, g_u, axes: Axes,
             rows2, acc2 = ops.dedup_adagrad(
                 tier.rows, tier.acc, all_slots, all_g, all_slots < h, lr, eps,
                 fused=True)
-            return CacheState(tier.keys, rows2, acc2)
+            return tier._replace(rows=rows2, acc=acc2)
         g_hot = jnp.zeros((h, d), g_u.dtype).at[all_slots].add(all_g,
                                                                mode="drop")
         return _tier_adagrad(tier, g_hot, lr, eps)
@@ -780,7 +869,8 @@ def flush_cache(
     1. write back hot rows + optimizer state to owner shards (no comm: the
        hot tier is replicated, owners take their slice) — 'psum' mode only;
     2. select the new global top-H by frequency (all_gather of local top-H);
-    3. load the new hot set (psum of owner contributions).
+    3. load the new hot set (psum of owner contributions), indexed
+       (``rank_index``) where the old tier carried an index.
     """
     rps, d = w_shard.shape
     h = cache.keys.shape[0]
@@ -806,8 +896,8 @@ def flush_cache(
     new_keys = jnp.sort(jnp.where(tvals > 0, all_ids[tidx], rows_padded))
 
     # ---- 3. load new hot set ------------------------------------------------
-    new_cache = _load_tier(w_shard, acc_shard, new_keys, base, rps,
-                           rows_padded, axes)
+    new_cache = _reindexed(_load_tier(w_shard, acc_shard, new_keys, base, rps,
+                                      rows_padded, axes), cache, rows_padded)
 
     counts_shard = (counts_shard.astype(jnp.float32) * decay).astype(counts_shard.dtype)
     return w_shard, acc_shard, counts_shard, new_cache
@@ -855,7 +945,8 @@ def flush_cache_l2(
        H1 become the new L1 hot tier, the next H2 the new L2 host tier — so
        the tiers are disjoint by construction and L2 holds exactly the skew
        tail that overflows the device-resident budget;
-    3. reload both tiers from the (just-synced) master shards.
+    3. reload both tiers from the (just-synced) master shards, each indexed
+       (``rank_index``) where the tier it replaces carried an index.
 
     Degenerate tiers (0 rows) are handled: an empty L1 makes this equivalent
     to a single-tier flush of L2 and vice versa.
@@ -886,8 +977,10 @@ def flush_cache_l2(
     keys2 = jnp.sort(keys_ranked[h1:])   # next H2    -> host tier
 
     # ---- 3. reload both tiers from master -----------------------------------
-    new_l1 = _load_tier(w_shard, acc_shard, keys1, base, rps, rows_padded, axes)
-    new_l2 = _load_tier(w_shard, acc_shard, keys2, base, rps, rows_padded, axes)
+    new_l1 = _reindexed(_load_tier(w_shard, acc_shard, keys1, base, rps,
+                                   rows_padded, axes), cache, rows_padded)
+    new_l2 = _reindexed(_load_tier(w_shard, acc_shard, keys2, base, rps,
+                                   rows_padded, axes), l2, rows_padded)
 
     counts_shard = (counts_shard.astype(jnp.float32) * decay).astype(counts_shard.dtype)
     return w_shard, acc_shard, counts_shard, new_l1, new_l2
@@ -951,7 +1044,7 @@ def _carry_exact_rows(tier: CacheState, old1: CacheState, old2: CacheState,
         found = (old.keys[pc] == tier.keys) & (tier.keys < rows_padded)
         rows = jnp.where(found[:, None], jnp.take(old.rows, pc, axis=0), rows)
         acc = jnp.where(found[:, None], jnp.take(old.acc, pc, axis=0), acc)
-    return CacheState(tier.keys, rows, acc)
+    return tier._replace(rows=rows, acc=acc)
 
 
 def flush_cache_narrow(
@@ -979,7 +1072,8 @@ def flush_cache_narrow(
        (``_carry_exact_rows``): only ids crossing the hot/cold boundary pass
        through the projection, so a persistently hot id trains at the full
        width indefinitely while a cooled id is narrowed to its best
-       rank-``d`` approximation.
+       rank-``d`` approximation. Each new tier is indexed (``rank_index``)
+       where the tier it replaces carried an index.
 
     In 'stale' mode (``write_back=False``) the narrow master is already
     exact and the tiers are read-only widened copies — no write-back, and no
@@ -1016,6 +1110,8 @@ def flush_cache_narrow(
     if write_back:
         new_l1 = _carry_exact_rows(new_l1, cache, l2, rows_padded)
         new_l2 = _carry_exact_rows(new_l2, cache, l2, rows_padded)
+    new_l1 = _reindexed(new_l1, cache, rows_padded)
+    new_l2 = _reindexed(new_l2, l2, rows_padded)
 
     counts_shard = (counts_shard.astype(jnp.float32) * decay).astype(counts_shard.dtype)
     return w_shard, acc_shard, counts_shard, new_l1, new_l2

@@ -49,7 +49,8 @@ def batch_specs(batch: Any, axes: Axes) -> Any:
 
 
 def emb_state_specs(axes: Axes, with_l2: bool = False,
-                    with_proj: bool = False) -> EmbeddingState:
+                    with_proj: bool = False,
+                    with_index: bool = False) -> EmbeddingState:
     """Specs for one packed group's EmbeddingState (table MP, tiers DP).
 
     ``with_l2`` mirrors whether the group's state carries an L2 host tier
@@ -60,12 +61,15 @@ def emb_state_specs(axes: Axes, with_l2: bool = False,
     master
     (``plan.narrow_width(gid) < dim``): the learned ``[d, D]`` up-projection
     is replicated — its gradient is psum'd so replicas stay bit-identical.
+    ``with_index`` mirrors a hot tier with rows (``plan.cache_rows[gid] >
+    0``), which carries the rank index of its keys, replicated like them.
     """
     return EmbeddingState(
         w=P(axes, None),
         acc=P(axes, None),
         counts=P(axes),
-        cache=CacheState(keys=P(), rows=P(), acc=P()),
+        cache=(CacheState(keys=P(), rows=P(), acc=P(), bits=P(), base=P())
+               if with_index else CacheState(keys=P(), rows=P(), acc=P())),
         l2=CacheState(keys=P(), rows=P(), acc=P()) if with_l2 else None,
         proj=ProjState(kernel=P(None, None), acc=P(None, None))
         if with_proj else None,
@@ -76,7 +80,8 @@ def emb_specs(plan: PicassoPlan, axes: Axes) -> Dict[str, EmbeddingState]:
     """Specs for the full per-group embedding dict (the ``"emb"`` subtree)."""
     return {str(g.gid): emb_state_specs(
         axes, with_l2=plan.l2_rows.get(g.gid, 0) > 0,
-        with_proj=plan.narrow_width(g.gid) < g.dim)
+        with_proj=plan.narrow_width(g.gid) < g.dim,
+        with_index=plan.cache_rows.get(g.gid, 0) > 0)
             for g in plan.groups}
 
 

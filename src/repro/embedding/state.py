@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.packed_embedding import (CacheState, ProjState, init_cache,
-                                         proj_pinv)
+                                         proj_pinv, rank_words,
+                                         with_rank_index)
 from repro.core.packing import PackedGroup, PicassoPlan
 
 
@@ -32,7 +33,8 @@ class EmbeddingState(NamedTuple):
     #                      group's NARROW width for picasso_narrow groups)
     acc: jnp.ndarray     # [rows, 1]   adagrad accumulator
     counts: jnp.ndarray  # [rows]      FCounter (warm-up + running stats)
-    cache: CacheState    # replicated hot tier (L1) — always model width
+    cache: CacheState    # replicated hot tier (L1) — always model width;
+    #                      carries the rank index of its keys when it has rows
     l2: Optional[CacheState] = None  # host-memory tier (L2), None = no tier
     proj: Optional[ProjState] = None  # learned [d, D] up-projection; set
     #   exactly when the master is narrow (None keeps the pre-narrow pytree
@@ -72,7 +74,8 @@ def init_group_state(key: jax.Array, group: PackedGroup, hot_rows: int,
         w=w,
         acc=jnp.zeros((group.rows, 1), dtype),
         counts=jnp.zeros((group.rows,), jnp.int32),
-        cache=init_cache(hot_rows, group.dim, group.rows, dtype),
+        cache=with_rank_index(init_cache(hot_rows, group.dim, group.rows,
+                                         dtype), group.rows),
         l2=(init_cache(l2_rows, group.dim, group.rows, dtype)
             if l2_rows > 0 else None),
         proj=(init_proj(group.gid, width, group.dim, dtype)
@@ -98,6 +101,7 @@ def abstract_embedding_state(plan: PicassoPlan, dtype=jnp.float32) -> Dict[int, 
         h = plan.cache_rows.get(g.gid, 0)
         h2 = plan.l2_rows.get(g.gid, 0)
         nd = plan.narrow_width(g.gid)
+        words = rank_words(g.rows)
         out[g.gid] = EmbeddingState(
             w=jax.ShapeDtypeStruct((g.rows, nd), dtype),
             acc=jax.ShapeDtypeStruct((g.rows, 1), dtype),
@@ -106,6 +110,9 @@ def abstract_embedding_state(plan: PicassoPlan, dtype=jnp.float32) -> Dict[int, 
                 keys=jax.ShapeDtypeStruct((h,), jnp.int32),
                 rows=jax.ShapeDtypeStruct((h, g.dim), dtype),
                 acc=jax.ShapeDtypeStruct((h, 1), dtype),
+                **({"bits": jax.ShapeDtypeStruct((words,), jnp.uint32),
+                    "base": jax.ShapeDtypeStruct((words,), jnp.int32)}
+                   if h > 0 else {}),
             ),
             l2=(CacheState(
                 keys=jax.ShapeDtypeStruct((h2,), jnp.int32),
@@ -213,7 +220,7 @@ def tier_gates(plan: PicassoPlan, gid: int, *, use_cache: bool = True,
 
 
 def _np_tier(st) -> CacheState:
-    return CacheState(*(np.asarray(jax.device_get(x)) for x in st))
+    return jax.tree.map(lambda x: np.asarray(jax.device_get(x)), st)
 
 
 def _np_write_back(w: np.ndarray, acc: np.ndarray, tier: CacheState) -> None:
@@ -365,6 +372,7 @@ def _migrate_group(group: PackedGroup, st: EmbeddingState,
         cache = _np_load_tier(w_wide, acc, keys1, rows_padded, dtype)
     else:  # allocated (plan budgets rows) but inert under the new strategy
         cache = _np_empty_tier(h1_new, group.dim, rows_padded, dtype)
+    cache = with_rank_index(cache, rows_padded)
     l2: Optional[CacheState] = None
     if h2_new > 0:
         l2 = (_np_load_tier(w_wide, acc, keys2, rows_padded, dtype)
@@ -418,9 +426,11 @@ def _reshard_group_state(group: PackedGroup, st: EmbeddingState
             return None
         keys = np.asarray(jax.device_get(tier.keys))
         keys = np.where(keys >= cut, r_new, keys).astype(np.int32)
-        return CacheState(keys=keys,
-                          rows=np.asarray(jax.device_get(tier.rows)),
-                          acc=np.asarray(jax.device_get(tier.acc)))
+        out = CacheState(keys=keys,
+                         rows=np.asarray(jax.device_get(tier.rows)),
+                         acc=np.asarray(jax.device_get(tier.acc)))
+        # the index is over the row space, so it is rebuilt for the new one
+        return out if tier.bits is None else with_rank_index(out, r_new)
 
     proj = None
     if st.proj is not None:

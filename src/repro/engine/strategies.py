@@ -189,6 +189,7 @@ class PicassoStrategy(LookupStrategy):
             capacity=self.capacity[gid],
             hot_keys=st.cache.keys if cache_on else None,
             hot_rows=st.cache.rows if cache_on else None,
+            hot_index=st.cache.index if cache_on else None,
             fused=self.use_fused)
 
     def apply_grads(self, st, gid, ctx, g_rows, *, cache_on=False, l2_on=False):
@@ -268,6 +269,7 @@ class PicassoL2Strategy(PicassoStrategy):
             capacity=self.capacity[gid],
             hot_keys=st.cache.keys if cache_on else None,
             hot_rows=st.cache.rows if cache_on else None,
+            hot_index=st.cache.index if cache_on else None,
             l2_keys=st.l2.keys, l2_rows=st.l2.rows,
             fused=self.use_fused)
 
@@ -332,6 +334,7 @@ class PicassoNarrowStrategy(PicassoL2Strategy):
             capacity=self.capacity[gid],
             hot_keys=st.cache.keys if cache_on else None,
             hot_rows=st.cache.rows if cache_on else None,
+            hot_index=st.cache.index if cache_on else None,
             l2_keys=st.l2.keys if with_l2 else None,
             l2_rows=st.l2.rows if with_l2 else None,
             fused=self.use_fused)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core.packed_embedding import with_rank_index
 
 try:  # optional: plain .npy files when the container lacks zstandard
     import zstandard
@@ -77,6 +78,24 @@ def _flatten(tree) -> Dict[str, Any]:
     return flat
 
 
+def _is_rank_index(name: str) -> bool:
+    """A hot tier's rank index leaf (``cache/bits``, ``cache/base``): derived
+    from the tier's keys, so a checkpoint may lack it."""
+    return name.split(_SEP)[-2:] in (["cache", "bits"], ["cache", "base"])
+
+
+def _with_rank_index(template, node):
+    """An embedding state restored from a checkpoint written without its hot
+    tier's rank index gets the index rebuilt from the tier's keys, over the
+    restored master's row space."""
+    cache = getattr(node, "cache", None)
+    if (cache is None or getattr(template.cache, "bits", None) is None
+            or (cache.bits is not None and cache.base is not None)):
+        return node
+    return node._replace(cache=with_rank_index(
+        cache._replace(bits=None, base=None), node.w.shape[0]))
+
+
 def _unflatten_into(template, flat: Dict[str, Any]):
     def rec(prefix, node):
         if node is None:
@@ -87,8 +106,8 @@ def _unflatten_into(template, flat: Dict[str, Any]):
         if hasattr(node, "_fields"):
             vals = {k: rec(f"{prefix}{_SEP}{k}" if prefix else str(k), getattr(node, k))
                     for k in node._fields}
-            return type(node)(**vals)
-        return flat[prefix]
+            return _with_rank_index(node, type(node)(**vals))
+        return flat.get(prefix) if _is_rank_index(prefix) else flat[prefix]
 
     return rec("", template)
 
@@ -280,6 +299,8 @@ def restore_checkpoint(ckpt_dir: str, template: Any, step: Optional[int] = None,
     out = {}
     for name, t in tflat.items():
         info = manifest.get(name)
+        if info is None and _is_rank_index(name):
+            continue  # rebuilt from the tier's keys (_with_rank_index)
         if info is None:
             raise KeyError(
                 f"checkpoint step_{step:08d} has no leaf {name!r} — the "

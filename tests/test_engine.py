@@ -174,7 +174,7 @@ def test_flush_cache_roundtrip(mesh1):
         out_specs=(P(AXES, None), P(AXES, None), P(AXES),
                    pe.CacheState(P(), P(), P())),
         check_vma=False))(
-        w, acc, counts, *cache)
+        w, acc, counts, cache.keys, cache.rows, cache.acc)
     cache2 = pe.CacheState(*cache2)
     keys = np.asarray(cache2.keys)
     assert set(keys[keys < v]) == set(range(v - h, v))  # top-8 hottest rows

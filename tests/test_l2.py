@@ -79,16 +79,19 @@ def test_state_structure_with_and_without_l2():
     assert st.l2.rows.shape == (16, 4)
     st0 = init_embedding_state(jax.random.PRNGKey(0), plan_no)[gid]
     assert st0.l2 is None
-    # None collapses: unbudgeted states keep the pre-L2 leaf count
-    assert len(jax.tree.leaves(st0)) == 6
-    assert len(jax.tree.leaves(st)) == 9
+    # None collapses: unbudgeted states keep the pre-L2 leaf count (the
+    # master's three, the hot tier's three and its rank index's two); the
+    # L2 tier adds its three and no index
+    assert len(jax.tree.leaves(st0)) == 8
+    assert len(jax.tree.leaves(st)) == 11
+    assert st.l2.bits is None and st.cache.bits is not None
     # specs mirror the state structure leaf-for-leaf (shard_map requires it)
     from jax.sharding import PartitionSpec as P
     is_spec = lambda x: isinstance(x, P)  # noqa: E731
     assert len(jax.tree.leaves(emb_specs(plan_l2, AXES)[str(gid)],
-                               is_leaf=is_spec)) == 9
+                               is_leaf=is_spec)) == 11
     assert len(jax.tree.leaves(emb_specs(plan_no, AXES)[str(gid)],
-                               is_leaf=is_spec)) == 6
+                               is_leaf=is_spec)) == 8
 
 
 # ------------------------------------------------------------- probe order

@@ -5,7 +5,9 @@ path's shapes (d=10, a global batch of 16384 x 39 ids on one chip) and asks
 the TPU compiler for the executable, which raises what the chip's compiler
 would raise. Every kernel that ``ops.TPU_KERNELS`` turns on must compile,
 and every kernel the table keeps on its reference must still be refused —
-otherwise the table is stale in one direction or the other.
+otherwise the table is stale in one direction or the other. The DeepFM
+train step itself is compiled too (at the smoke size), with and without the
+hot tier's rank index.
 
 The topology is described inside a module-scoped fixture (never at import,
 in ``conftest.py`` or in a ``skipif``): only one process at a time may load
@@ -13,11 +15,13 @@ the TPU library, so the call must run in the worker that was given this
 file, after its first test has started.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.cross_layer import cross_layer_pallas
@@ -170,3 +174,79 @@ def test_dispatch_table_agrees_with_compiler(compile_op):
                          if entry is True else
                          f"table: reference ({entry}), but it compiles")
     assert not wrong, wrong
+
+
+@pytest.fixture(scope="module")
+def deepfm_steps(topo):
+    """The donating DeepFM train step compiled for one v5e chip, once with
+    the hot tier carrying its rank index (as the program builds the state)
+    and once with the index taken off (the tier then searched): ``name ->
+    (compiled, plan)``. The kernel dispatch is steered as on a TPU."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.configs import get_config
+    from repro.core.packing import make_plan
+    from repro.data.synthetic import batch_stream
+    from repro.dist.sharding import batch_specs, to_named
+    from repro.models.wdl import WDLModel
+    from repro.train.train_step import TrainConfig, init_state, make_train_step
+
+    gb, axes = 256, ("data", "model")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), axes)
+    cfg = get_config("deepfm", smoke=True)
+    plan = make_plan(cfg, world=1, per_device_batch=gb, hot_bytes=1 << 20,
+                     flush_iters=20, warmup_iters=10)
+    model = WDLModel(cfg, plan)
+
+    def place(tree, specs):
+        return jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(
+            np.shape(x), x.dtype, sharding=sh), tree, to_named(mesh, specs))
+
+    def strip(tree):
+        return {**tree, "emb": {k: v._replace(cache=v.cache._replace(
+            bits=None, base=None)) for k, v in tree["emb"].items()}}
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    ops._BACKEND = (True, False)
+    try:
+        step, sspecs = make_train_step(model, plan, mesh, axes, gb,
+                                       TrainConfig())
+        state = jax.eval_shape(lambda k: init_state(model, plan, k),
+                               jax.random.PRNGKey(0))
+        batch = next(batch_stream(cfg, gb, seed=0))
+        batch = place(batch, batch_specs(batch, axes))
+        out = {name: (step.lower(place(prep(state), prep(sspecs)),
+                                 batch).compile(), plan)
+               for name, prep in (("indexed", lambda t: t),
+                                  ("searched", strip))}
+    finally:
+        ops.reset_backend_cache()
+        jax.config.update("jax_enable_compilation_cache", was_on)
+    yield out
+
+
+def test_deepfm_step_arguments_grow_by_the_rank_index(deepfm_steps):
+    """The index adds at most 8 bytes a 32-row word (uint32 bits, int32
+    base) for each group with a tier, plus 1 MiB for the TPU's padding."""
+    (indexed, plan), (searched, _) = (deepfm_steps["indexed"],
+                                      deepfm_steps["searched"])
+    grow = (indexed.memory_analysis().argument_size_in_bytes
+            - searched.memory_analysis().argument_size_in_bytes)
+    bound = sum(8 * (g.rows // 32 + 1) for g in plan.groups
+                if plan.cache_rows.get(g.gid, 0) > 0) + (1 << 20)
+    assert 0 < grow <= bound, (grow, bound)
+
+
+def _probe_loops(compiled):
+    return [ln for ln in compiled.as_text().splitlines()
+            if " while(" in ln
+            and re.search(r'op_name="[^"]*/tier_probe/', ln)]
+
+
+def test_deepfm_step_probes_the_tier_without_a_loop(deepfm_steps):
+    """With the index the ``tier_probe`` scope holds no ``while`` loop; the
+    searched tier's binary search is one."""
+    assert not _probe_loops(deepfm_steps["indexed"][0])
+    assert len(_probe_loops(deepfm_steps["searched"][0])) == 1
