@@ -1,7 +1,7 @@
 """Plain reference of the wide-and-deep models these configurations run:
 DeepFM (FM + linear + MLP over the field embeddings) and DCN-v2 (stacked
-full-rank cross network, then the MLP), trained with Adam on the dense
-parameters and row-wise Adagrad on the embedding rows.
+full-rank or low-rank cross network, then the MLP), trained with Adam on the
+dense parameters and row-wise Adagrad on the embedding rows.
 
 Straight ``jax.numpy`` in the precision asked for (float32 at ``highest``
 matmul precision for the reference, lower for the control), no kernels, no
@@ -15,14 +15,22 @@ thing shared with the program is the model's definition, written out here:
   number coprime with ``vocab_f`` from ``2654435761 mod vocab_f`` up and
   ``s_f = crc32(f) mod 10007``. Two ids may share a row, as in any hashed
   embedding.
-* Model. ``x_f`` is field f's row (one id per field, sum pooling), ``X`` the
-  ``[B, F, d]`` stack in configuration order, ``base`` its ``[B, F*d]``
-  flattening followed by the numeric features. ``fm`` adds
-  ``0.5 * sum_d((sum_f x)^2 - sum_f x^2)`` to the logit, ``linear`` adds
-  ``sum_f x_f @ W_f``; ``cross`` replaces the MLP input with
-  ``x_{l+1} = base * (x_l @ W_l + b_l) + x_l`` (``x_0 = base``). The MLP
-  has ReLU between layers and one output. The loss is the mean binary
-  cross-entropy with logits over the global batch.
+* Configuration keys beyond the sizes: ``multi_hot``, the ids a sample of
+  each categorical field in field order (1 each where absent; a numeric
+  bucket field has 1); ``bottom_mlp``, the widths of an MLP over the
+  ``n_dense`` numeric features with ReLU after every layer (torchrec's
+  ``DenseArch``); and a ``cross`` interaction's ``low_rank: r``.
+* Model. ``x_f`` is the sum of the rows of field f's ids (its bag), ``X``
+  the ``[B, F, d]`` stack in configuration order, ``base`` its ``[B, F*d]``
+  flattening followed by the numeric features, or by the bottom MLP's
+  output where there is one. This is the program's order; torchrec's DLRM
+  puts the dense part first, a permutation of the same equations. ``fm``
+  adds ``0.5 * sum_d((sum_f x)^2 - sum_f x^2)`` to the logit, ``linear``
+  adds ``sum_f x_f @ W_f``; ``cross`` replaces the MLP input with
+  ``x_{l+1} = base * (x_l @ W_l + b_l) + x_l`` (``x_0 = base``), or with
+  ``low_rank`` ``x_{l+1} = base * ((x_l @ W_down_l) @ W_up_l + b_l) + x_l``.
+  The MLP has ReLU between layers and one output. The loss is the mean
+  binary cross-entropy with logits over the global batch.
 * Updates. Adam (b1 0.9, b2 0.999, eps 1e-8) on every dense leaf; on each
   table the gradients of a step's duplicate rows are summed and a touched
   row takes ``acc += mean_d(g^2)``, ``w -= lr * g / sqrt(acc + eps)``.
@@ -37,7 +45,7 @@ from __future__ import annotations
 import json
 import math
 import zlib
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,11 +59,23 @@ EMB_STREAM0, DENSE_STREAM0 = 0, 1_000_000
 
 
 # ------------------------------------------------------------------ model
-def fields(cfg: Dict) -> List[Tuple[str, int, int]]:
-    """``(name, vocab, dim)`` of every field, in configuration order."""
+class Field(NamedTuple):
+    name: str
+    vocab: int
+    dim: int
+    hot: int     # ids a sample, sum-pooled
+
+
+def fields(cfg: Dict) -> List[Field]:
+    """Every field, in configuration order."""
     d = cfg["embed_dim"]
-    out = [(f"cat_{i}", int(v), d) for i, v in enumerate(cfg["categorical_vocab"])]
-    out += [(f"numb_{i}", int(cfg["numeric_buckets"]), d)
+    vocab = cfg["categorical_vocab"]
+    hot = cfg.get("multi_hot", [1] * len(vocab))
+    if len(hot) != len(vocab) or min(hot) < 1:
+        raise ValueError(f"multi_hot names {len(hot)} bags of at least one id, "
+                         f"one for each of the {len(vocab)} categorical fields")
+    out = [Field(f"cat_{i}", int(v), d, int(k)) for i, (v, k) in enumerate(zip(vocab, hot))]
+    out += [Field(f"numb_{i}", int(cfg["numeric_buckets"]), d, 1)
             for i in range(cfg.get("numeric_bucket_fields", 0))]
     return out
 
@@ -83,23 +103,31 @@ def dense_spec(cfg: Dict) -> List[Tuple[Tuple[str, ...], Tuple[int, ...], float]
     """Every dense leaf as ``(path, shape, init std)``; the path names match
     the program's parameter tree, std 0 means zeros."""
     fs = fields(cfg)
-    base = sum(d for _, _, d in fs) + cfg.get("n_dense", 0)
-    spec = []
-    deep_in = base
+    n_dense, bottom = cfg.get("n_dense", 0), list(cfg.get("bottom_mlp", []))
+    spec = _mlp_spec("bottom", [n_dense] + bottom)
+    base = sum(f.dim for f in fs) + (bottom[-1] if bottom else n_dense)
     for n, it in enumerate(cfg["interactions"]):
         kind = it["kind"]
         if kind == "linear":
-            spec += [((f"i{n}_linear", name), (d, 1), 0.01) for name, _, d in fs]
+            spec += [((f"i{n}_linear", f.name), (f.dim, 1), 0.01) for f in fs]
         elif kind == "cross":
+            r = it.get("low_rank")
             for l in range(it["n_layers"]):
-                spec += [((f"i{n}_cross", f"l{l}", "w"), (base, base), 1 / math.sqrt(base)),
-                         ((f"i{n}_cross", f"l{l}", "b"), (base,), 0.0)]
+                at = (f"i{n}_cross", f"l{l}")
+                spec += ([(at + ("w_down",), (base, r), 1 / math.sqrt(base)),
+                          (at + ("w_up",), (r, base), 1 / math.sqrt(r))] if r else
+                         [(at + ("w",), (base, base), 1 / math.sqrt(base))])
+                spec.append((at + ("b",), (base,), 0.0))
         elif kind != "fm":
             raise ValueError(f"the reference has no interaction {kind!r}")
-    dims = [deep_in] + list(cfg["mlp_dims"]) + [1]
+    return spec + _mlp_spec("top", [base] + list(cfg["mlp_dims"]) + [1])
+
+
+def _mlp_spec(name: str, dims: List[int]):
+    spec = []
     for l in range(len(dims) - 1):
-        spec += [(("top", f"l{l}", "w"), (dims[l], dims[l + 1]), math.sqrt(2.0 / dims[l])),
-                 (("top", f"l{l}", "b"), (dims[l + 1],), 0.0)]
+        spec += [((name, f"l{l}", "w"), (dims[l], dims[l + 1]), math.sqrt(2.0 / dims[l])),
+                 ((name, f"l{l}", "b"), (dims[l + 1],), 0.0)]
     return spec
 
 
@@ -114,12 +142,33 @@ def init_dense(cfg: Dict, seed: int, dtype=jnp.float32) -> Dict:
     return out
 
 
+def _mlp(p: Dict, x: jnp.ndarray, relu_last: bool) -> jnp.ndarray:
+    for l in range(len(p)):
+        x = x @ p[f"l{l}"]["w"] + p[f"l{l}"]["b"]
+        if relu_last or l < len(p) - 1:
+            x = jax.nn.relu(x)
+    return x
+
+
+def pool(cfg: Dict, rows: jnp.ndarray) -> jnp.ndarray:
+    """``rows``: ``[B, T, d]``, a sample's ids field after field, each
+    field's bag in turn. Returns ``[B, F, d]``, each bag's rows summed."""
+    hot = [f.hot for f in fields(cfg)]
+    seg = np.repeat(np.arange(len(hot)), hot)
+    return jax.ops.segment_sum(jnp.swapaxes(rows, 0, 1), seg,
+                               num_segments=len(hot)).swapaxes(0, 1)
+
+
 def logits(cfg: Dict, params: Dict, x: jnp.ndarray, dense_x) -> jnp.ndarray:
-    """``x``: ``[B, F, d]`` field rows; ``dense_x``: ``[B, n_dense]`` or None."""
+    """``x``: ``[B, F, d]`` pooled field rows; ``dense_x``: ``[B, n_dense]``
+    or None."""
     b = x.shape[0]
     base = x.reshape(b, -1)
     if dense_x is not None:
-        base = jnp.concatenate([base, dense_x.astype(x.dtype)], axis=-1)
+        dense_x = dense_x.astype(x.dtype)
+        if "bottom" in params:
+            dense_x = _mlp(params["bottom"], dense_x, relu_last=True)
+        base = jnp.concatenate([base, dense_x], axis=-1)
     wide = jnp.zeros((b, 1), x.dtype)
     deep = base
     for n, it in enumerate(cfg["interactions"]):
@@ -128,27 +177,23 @@ def logits(cfg: Dict, params: Dict, x: jnp.ndarray, dense_x) -> jnp.ndarray:
             wide = wide + 0.5 * (s * s - (x * x).sum(axis=1)).sum(-1, keepdims=True)
         elif it["kind"] == "linear":
             p = params[f"i{n}_linear"]
-            w = jnp.stack([p[name] for name, _, _ in fields(cfg)])  # [F, d, 1]
+            w = jnp.stack([p[f.name] for f in fields(cfg)])  # [F, d, 1]
             wide = wide + jnp.einsum("bfd,fdo->bo", x, w)
         elif it["kind"] == "cross":
             p = params[f"i{n}_cross"]
             h = base
             for l in range(it["n_layers"]):
-                h = base * (h @ p[f"l{l}"]["w"] + p[f"l{l}"]["b"]) + h
+                q = p[f"l{l}"]
+                z = (h @ q["w_down"]) @ q["w_up"] if "w_down" in q else h @ q["w"]
+                h = base * (z + q["b"]) + h
             deep = h
-    top = params["top"]
-    n_top = len(top)
-    for l in range(n_top):
-        deep = deep @ top[f"l{l}"]["w"] + top[f"l{l}"]["b"]
-        if l < n_top - 1:
-            deep = jax.nn.relu(deep)
-    return deep + wide
+    return _mlp(params["top"], deep, relu_last=False) + wide
 
 
 def mean_loss(cfg, params, table, idx, dense_x, labels):
     """``table``: every field's touched rows, one after another, ``[R, d]``;
-    ``idx``: ``[B, F]`` rows of it."""
-    z = logits(cfg, params, table[idx], dense_x).astype(jnp.float32)[:, 0]
+    ``idx``: ``[B, T]`` rows of it, each sample's bags one after another."""
+    z = logits(cfg, params, pool(cfg, table[idx]), dense_x).astype(jnp.float32)[:, 0]
     y = labels.astype(jnp.float32)
     ls = jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
     return ls.sum() / z.shape[0]
@@ -193,9 +238,9 @@ def _program(cfg: Dict, dtype, compared: int):
         return _PROGRAMS[key]
     from bench import weights
     fs = fields(cfg)
-    names = [f[0] for f in fs]
-    d = fs[0][2]
-    if any(f[2] != d for f in fs):
+    names = [f.name for f in fs]
+    d = fs[0].dim
+    if any(f.dim != d for f in fs):
         raise ValueError("the reference keeps one table of rows for fields of one width")
 
     def field_norms(x, field_of):
@@ -257,16 +302,16 @@ def readings(cfg: Dict, seed: int, batches: Sequence[Dict], dtype=jnp.float32,
     fs = fields(cfg)
     rows, stream, field_of, cols = [], [], [], []
     start = 0
-    for k, (name, vocab, _) in enumerate(fs):
-        hashed = np.stack([hashed_rows(name, vocab, b["fields"][name]["ids"][:, 0])
-                           for b in batches])                       # [S, B]
+    for k, f in enumerate(fs):
+        hashed = np.stack([hashed_rows(f.name, f.vocab, b["fields"][f.name]["ids"])
+                           for b in batches])                       # [S, B, hot]
         uniq = np.unique(hashed)
         rows.append(uniq)
         stream.append(np.full(uniq.shape, EMB_STREAM0 + k))
         field_of.append(np.full(uniq.shape, k))
         cols.append(start + np.searchsorted(uniq, hashed))
         start += uniq.shape[0]
-    idx = np.stack(cols, axis=-1).astype(np.int32)                   # [S, B, F]
+    idx = np.concatenate(cols, axis=-1).astype(np.int32)             # [S, B, T]
     dense_x = (np.stack([b["dense"] for b in batches]) if cfg.get("n_dense", 0) else None)
     labels = np.stack([b["labels"] for b in batches])
     with jax.default_matmul_precision("highest"):

@@ -161,7 +161,7 @@ def run(root: Path, cell: str, seed: int, seconds: float, trace: bool, *,
     del state, m, batch
     for r in steps:
         r.pop("_m", None)
-    ref_batches = [generator.batch_at(mix, sut.field_pairs, n_dense, gb, seed, i)
+    ref_batches = [generator.batch_at(mix, sut.fields, n_dense, gb, seed, i)
                    for i in range(n_setup)]
     t_ref = time.perf_counter()
     want = ref.readings(cfg, seed, ref_batches)
@@ -191,7 +191,7 @@ def run(root: Path, cell: str, seed: int, seconds: float, trace: bool, *,
             steps=steps, window_s=window_s, n_steps=n_win, global_batch=gb,
             chips=len(dev_used), cfg=cfg, trace=red, flops=flops,
             peak=peaks.peaks(devs[0].device_kind), mix=mix, seed=seed,
-            first_window_batch=n_setup, field_pairs=sut.field_pairs,
+            first_window_batch=n_setup, fields=sut.fields,
             n_dense=n_dense)
         for m in registry.per_layer(bm, cell):
             v = registry.metric_reader(root, m["name"])(ctx)
@@ -230,7 +230,7 @@ def first_steps(sut, seed: int, mix: Dict, batch_fault=None):
     seed_w = weights.seed_words(seed)
     state = sutmod.init_state(sut, seed)
     grad_norms, change_norms, table_norms = sutmod.make_readers(sut)
-    gen = generator.stream(mix, sut.field_pairs, sut.cfg_json.get("n_dense", 0),
+    gen = generator.stream(mix, sut.fields, sut.cfg_json.get("n_dense", 0),
                            sut.global_batch, seed)
     if batch_fault is not None:
         gen = map(batch_fault, gen)

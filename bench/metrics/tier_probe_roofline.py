@@ -3,7 +3,7 @@ searches of ``mp_lookup``, which say whether a tier holds each distinct id
 and at which slot; the hit rows are fetched under ``stitch``): the least
 time of the probe's work on the roofline over its device time on the
 fullest chip. The work is what any correct search moves per distinct id a
-chip looked up (``scopes.tier_probe_least``), for the distinct ids of the
+chip looked up (``flops.tier_probe_least``), for the distinct ids of the
 window's steps (``scopes.distinct_rows``: what the program's
 ``distinct_ids`` counter sums over the chips, so divided by them).
 
@@ -23,5 +23,5 @@ def read(ctx):
         return None
     distinct = scopes.distinct_rows(ctx, run.micro)
     least = ctx.flops.least_seconds(
-        scopes.tier_probe_least(distinct / ctx.chips), ctx.peak)
+        ctx.flops.tier_probe_least(distinct / ctx.chips), ctx.peak)
     return 100.0 * least / spent

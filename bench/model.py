@@ -37,22 +37,24 @@ class SUT:
     global_batch: int
 
     @property
-    def field_pairs(self):
-        return [(f.name, f.vocab) for f in self.wdl.fields]
+    def fields(self):
+        """The reference's ``Field``s, as ``generator`` takes them."""
+        return self.ref.fields(self.cfg_json)
 
     def stream_of(self, name: str) -> int:
-        return self.ref.EMB_STREAM0 + [f[0] for f in self.ref.fields(self.cfg_json)].index(name)
+        return self.ref.EMB_STREAM0 + [f.name for f in self.fields].index(name)
 
 
 def wdl_config(cfg: Dict, ref):
     from repro.configs.base import FeatureField, InteractionSpec, WDLConfig
-    fields = tuple(FeatureField(name=n, vocab=v, dim=d, max_len=1, pooling="sum")
-                   for n, v, d in ref.fields(cfg))
+    fields = tuple(FeatureField(name=f.name, vocab=f.vocab, dim=f.dim, max_len=f.hot,
+                                pooling="sum") for f in ref.fields(cfg))
     inter = tuple(InteractionSpec(it["kind"], kwargs={k: v for k, v in it.items()
                                                       if k != "kind"})
                   for it in cfg["interactions"])
     return WDLConfig(name=cfg["name"], fields=fields, n_dense=cfg.get("n_dense", 0),
-                     interactions=inter, mlp_dims=tuple(cfg["mlp_dims"]))
+                     interactions=inter, mlp_dims=tuple(cfg["mlp_dims"]),
+                     dense_arch=tuple(cfg.get("bottom_mlp", ())))
 
 
 def build(cfg: Dict, ref, step_wrapper=None) -> SUT:
@@ -87,7 +89,9 @@ def build(cfg: Dict, ref, step_wrapper=None) -> SUT:
 def _set(tree: Dict, path, value):
     node = tree
     for p in path[:-1]:
-        node = node[p]
+        node = node.get(p) if isinstance(node, dict) else None
+    if not isinstance(node, dict) or path[-1] not in node:
+        raise ValueError(f"the program has no dense leaf {'/'.join(path)}")
     if node[path[-1]].shape != value.shape:
         raise ValueError(f"dense leaf {'/'.join(path)}: program shape "
                          f"{node[path[-1]].shape}, reference {value.shape}")
@@ -190,11 +194,11 @@ def state_fn(sut: SUT):
     def build(seed):
         st = program_init(sut.model, plan, jax.random.PRNGKey(0))
         dense = jax.tree.map(lambda x: x, st["dense"])
+        for k, (path, shape, std) in enumerate(spec):
+            _set(dense, path, weights.leaf(seed, ref.DENSE_STREAM0 + k, shape, std))
         if len(jax.tree.leaves(dense)) != len(spec):
             raise ValueError(f"program has {len(jax.tree.leaves(dense))} dense "
                              f"leaves, the reference {len(spec)}")
-        for k, (path, shape, std) in enumerate(spec):
-            _set(dense, path, weights.leaf(seed, ref.DENSE_STREAM0 + k, shape, std))
         emb = {}
         for g in plan.groups:
             def local(seed, g=g):

@@ -55,7 +55,7 @@ def main(argv=None):
     programs = {"program", "no_write_back"} & set(args.kinds)
     sut = sutmod.build(cfg, ref) if programs else None
     n_dense = cfg.get("n_dense", 0)
-    fields = [(n, v) for n, v, _ in ref.fields(cfg)]
+    fields = ref.fields(cfg)
     n_setup = int(cfg["flush_iters"]) + 1
     for seed in args.seeds:
         batches = [generator.batch_at(mix, fields, n_dense, cfg["global_batch"], seed, i)

@@ -1,14 +1,15 @@
 """The program's named phases in a traced window: each device operation's
 scope path, device time by scope, idle gaps labelled by the program's own
-host spans, and the probe's least work.
+host spans, and the distinct rows the window's lookups worked on.
 
 The program names its phases in one table, ``repro.obs``: a scope
 (``jax.named_scope``) lands in the HLO ``op_name`` metadata of every
 operation traced inside it, and a span (``TraceAnnotation``) is a host span
 on the profiler's timeline. A scope path is the table's scope names in an
 operation's ``op_name``, outermost first, joined by ``/``:
-``sparse_lookup/tier_probe`` for the tier probe's binary search and its loop
-body (the hit rows' fetch is ``sparse_lookup/stitch``).
+``sparse_lookup/tier_probe`` for the tier probe, the L1 tier's rank-index
+read and the L2 tier's search (the hit rows' fetch is
+``sparse_lookup/stitch``).
 
 ``of(ctx)`` serves the per-layer readers of a ``--trace 1`` run. After the
 window it compiles the cell's step again from its configuration (the
@@ -38,7 +39,6 @@ except ImportError:  # a program that names no phases
 
 ROOT = Path(__file__).resolve().parents[1]
 PROGRAM_SPANS = obs.SPANS if obs is not None else ()
-PROBE_BYTES_PER_ID = 9
 
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?\bop_name="((?:[^"\\]|\\.)*)"')
 _WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
@@ -91,7 +91,7 @@ def step_text(ctx):
 
     sut = sutmod.build(ctx.cfg, registry.reference(ROOT, ctx.cfg))
     state = jax.eval_shape(sutmod.state_fn(sut), weights.seed_words(ctx.seed))
-    batch = generator.batch_at(ctx.mix, ctx.field_pairs, ctx.n_dense,
+    batch = generator.batch_at(ctx.mix, ctx.fields, ctx.n_dense,
                                ctx.global_batch, ctx.seed, ctx.first_window_batch)
     batch = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
@@ -193,29 +193,20 @@ def _length(iv: List[Tuple[int, int]]) -> float:
     return sum(b - a for a, b in tr._union(iv)) * 1e-9
 
 
-def tier_probe_least(n_distinct: float) -> Dict[str, float]:
-    """The least a hot-tier probe must move for ``n_distinct`` distinct ids:
-    read each id's key (4 B), write its slot (4 B) and its hit flag (1 B):
-    9 bytes an id. No FLOPs. Nothing for the tier's key array, since a
-    hashed tier reads only the slots it probes, nothing for padding, and
-    nothing for the hit rows, which the stitch fetches."""
-    return {"flops": 0, "bytes": PROBE_BYTES_PER_ID * n_distinct}
-
-
 def distinct_rows(ctx, micro: int) -> int:
-    """The distinct ids the window's lookups worked on, summed over steps,
+    """The distinct rows the window's lookups worked on, summed over steps,
     chips, micro-batches of ``micro`` samples and fields (one table a
-    field): what the program's ``distinct_ids`` counter sums, drawn again
-    from the seed with the reference's row map."""
+    field), every id of a bag counted: what the program's ``distinct_ids``
+    counter sums, drawn again from the seed with the reference's row map."""
     from bench import generator, registry
     ref = registry.reference(ROOT, ctx.cfg)
     total = 0
     for k in range(ctx.n_steps):
-        b = generator.batch_at(ctx.mix, ctx.field_pairs, ctx.n_dense,
+        b = generator.batch_at(ctx.mix, ctx.fields, ctx.n_dense,
                                ctx.global_batch, ctx.seed,
                                ctx.first_window_batch + k)
-        for name, vocab in ctx.field_pairs:
-            rows = ref.hashed_rows(name, vocab, b["fields"][name]["ids"][:, 0])
+        for f in ctx.fields:
+            rows = ref.hashed_rows(f.name, f.vocab, b["fields"][f.name]["ids"])
             for lo in range(0, ctx.global_batch, micro):
                 total += np.unique(rows[lo:lo + micro]).size
     return total

@@ -38,6 +38,42 @@ def test_dcn_v2_dense_step_flops():
     assert flops.dense_step_flops(cfg, 16384) == want
 
 
+def test_dlrm_dcnv2_dense_step_flops():
+    # MLPerf's DLRM-DCNv2: 26 fields at d=128, 13 numeric features through a
+    # bottom MLP 512-256-128, 3 cross layers at rank 512, top MLP
+    # 1024-1024-512-256-1, at B=4096 (bags change no dense width)
+    cfg = {"categorical_vocab": [1] * 26, "multi_hot": [100] * 26, "embed_dim": 128,
+           "n_dense": 13, "bottom_mlp": [512, 256, 128],
+           "interactions": [{"kind": "cross", "n_layers": 3, "low_rank": 512}],
+           "mlp_dims": [1024, 1024, 512, 256]}
+    # bottom 13-512-256-128: 6656 + 131072 + 32768 = 170,496
+    #   6 * 4096 * 170496 = 4,190,109,696
+    # D = 26*128 + 128 = 3456 (the bottom MLP's output, not the 13 features)
+    # B*D = 14,155,776; B*D*r = 7,247,757,312
+    # cross 3 * (12*B*D*r + 6*B*D) = 3 * (86,973,087,744 + 84,934,656)
+    #   = 261,174,067,200
+    # top 3456-1024-1024-512-256-1: 3538944 + 1048576 + 524288 + 131072 + 256
+    #   = 5,243,136; 6 * 4096 * 5243136 = 128,855,310,336
+    want = 4_190_109_696 + 261_174_067_200 + 128_855_310_336
+    assert want == 394_219_487_232
+    assert flops.dense_step_flops(cfg, 4096) == want
+    # full rank, the same widths: 3 * (6*B*D^2 + 6*B*D), about 3.4 times more
+    full = dict(cfg, interactions=[{"kind": "cross", "n_layers": 3}])
+    assert flops.dense_step_flops(full, 4096) - want == \
+        3 * 6 * 4096 * 3456 * 3456 - 3 * 12 * 4096 * 3456 * 512
+
+
+def test_cross_lowrank_kernel_counts():
+    # B=4096, D=3456, r=512: B*D = 14,155,776, B*D*r = 7,247,757,312,
+    # D*r = 1,769,472
+    fwd = flops.cross_lowrank_fwd(4096, 3456, 512)
+    assert fwd["flops"] == 4 * 7_247_757_312 + 3 * 14_155_776 == 29_033_496_576
+    assert fwd["bytes"] == 4 * (3 * 14_155_776 + 2 * 1_769_472 + 3456) == 184_038_912
+    bwd = flops.cross_lowrank_bwd(4096, 3456, 512)
+    assert bwd["flops"] == 12 * 7_247_757_312 + 5 * 14_155_776 == 87_043_866_624
+    assert bwd["bytes"] == 4 * (5 * 14_155_776 + 4 * 1_769_472 + 2 * 3456) == 311_454_720
+
+
 def test_fm_kernel_counts():
     # B=16384, F=39, d=10: B*F*d = 6,389,760
     assert flops.fm_fwd(16384, 39, 10) == {

@@ -1,6 +1,8 @@
 """The benchmark's configurations, reference and weights against the
 program's own definitions, on the CPU."""
 import dataclasses
+import hashlib
+import json
 import zlib
 from pathlib import Path
 
@@ -11,6 +13,7 @@ import pytest
 from bench import registry, weights
 
 ROOT = Path(__file__).resolve().parents[2]
+DATA = Path(__file__).resolve().parent / "data"
 CAP = 1 << 24
 
 
@@ -58,16 +61,129 @@ def test_every_seed_does_the_same_work():
     """Two seeds train on the same ids in the same order; one seed repeats
     itself; labels differ."""
     from bench import generator
+    ref = registry.reference(ROOT, {"reference": "wdl_reference"})
     mix = registry.traffic(ROOT, "zipf")
-    fields = [("cat_0", 1 << 24), ("cat_5", 3)]
+    fields = [ref.Field("cat_0", 1 << 24, 10, 1), ref.Field("cat_5", 3, 10, 1),
+              ref.Field("cat_9", 4096, 10, 8)]
     s1, s2 = 3_000_000_001, 3_000_000_002
     a = generator.batch_at(mix, fields, 13, 4096, s1, 5)
     again = generator.batch_at(mix, fields, 13, 4096, s1, 5)
     b = generator.batch_at(mix, fields, 13, 4096, s2, 5)
     other = generator.batch_at(mix, fields, 13, 4096, s1, 6)
-    for name, _ in fields:
-        np.testing.assert_array_equal(a["fields"][name]["ids"], again["fields"][name]["ids"])
-        np.testing.assert_array_equal(a["fields"][name]["ids"], b["fields"][name]["ids"])
+    assert a["fields"]["cat_9"]["ids"].shape == (4096, 8)
+    for f in fields:
+        np.testing.assert_array_equal(a["fields"][f.name]["ids"], again["fields"][f.name]["ids"])
+        np.testing.assert_array_equal(a["fields"][f.name]["ids"], b["fields"][f.name]["ids"])
     assert not np.array_equal(a["fields"]["cat_0"]["ids"], other["fields"]["cat_0"]["ids"])
     np.testing.assert_array_equal(a["labels"], again["labels"])
     assert not np.array_equal(a["labels"], b["labels"])
+
+
+def test_bag_is_its_first_id_and_a_fixed_uniform_tail():
+    """torchrec's ``Multihot`` (uniform): a bag's first id comes from the
+    mix as a one-hot field's does, and its other ids are fixed for that
+    first id and uniform over the field's rows."""
+    from bench import generator
+    ref = registry.reference(ROOT, {"reference": "wdl_reference"})
+    mix = registry.traffic(ROOT, "zipf")
+    vocab = 40_000_000
+    one = [ref.Field("cat_0", 4096, 10, 1), ref.Field("cat_1", vocab, 10, 1)]
+    bags = [ref.Field("cat_0", 4096, 10, 3), ref.Field("cat_1", vocab, 10, 100)]
+    a = generator.batch_at(mix, one, 0, 4096, 3_000_000_041, 2)
+    b = generator.batch_at(mix, bags, 0, 4096, 3_000_000_041, 2)
+    for f in bags:
+        np.testing.assert_array_equal(b["fields"][f.name]["ids"][:, :1],
+                                      a["fields"][f.name]["ids"])
+    small = b["fields"]["cat_0"]["ids"]              # zipf: first ids repeat
+    first, at = np.unique(small[:, 0], return_index=True)
+    assert first.size < 4096
+    np.testing.assert_array_equal(small, small[at][np.searchsorted(first, small[:, 0])])
+    big = b["fields"]["cat_1"]["ids"].astype(np.int64)
+    assert big.shape == (4096, 100)
+    # the tails of distinct first ids: uniform over 40M rows (mean vocab/2
+    # to 0.5%, almost no repeats), so zipf's low ids do not pile up
+    tail = big[np.unique(big[:, 0], return_index=True)[1], 1:]
+    assert tail.shape[0] > 1000 and 0 <= tail.min() and tail.max() < vocab
+    assert abs(tail.mean() / vocab - 0.5) < 0.005
+    assert np.unique(tail).size > 0.99 * tail.size
+    other = generator.batch_at(dict(mix, id_pool_seed=mix["id_pool_seed"] + 1), bags, 0,
+                               4096, 3_000_000_041, 2)
+    assert not np.array_equal(other["fields"]["cat_1"]["ids"], big)
+
+
+# sha256 of the ids of every field, field after field (int32, C order), and
+# of the labels at seed 3,000,000,031, as the generator drew them before
+# fields could carry bags: a field of one id a sample draws as it did
+PINNED = {
+    ("zipf", 0): ("abeff01313d5fec721511fda1f74397c47e525b6a8d55a588ecb5a3941051592",
+                  "482981f961c6ce6d2154b8aeb128c692e2341b9a3847ca66165dc39a70e9ac94"),
+    ("zipf", 57): ("f3ba286052d6d25c542a562a25418cbd83d62b710e1f91fb687f02a0df3bfd7f",
+                   "bedc9d0d5c9a815e287c0e16aab827bae01f554ad47fed02d10d127ec99ae833"),
+    ("uniform", 0): ("11ca0cb6e71dc69e1d7ff3d0e6499411fc3a956af98712395bec6ca9c5a6edbe",
+                     "482981f961c6ce6d2154b8aeb128c692e2341b9a3847ca66165dc39a70e9ac94"),
+    ("uniform", 57): ("4063801d47300439f99076ebc5d5e885e51a0a2a18518a06075078ea3f368c3b",
+                      "bedc9d0d5c9a815e287c0e16aab827bae01f554ad47fed02d10d127ec99ae833"),
+}
+
+
+@pytest.mark.parametrize("mix_name,i", sorted(PINNED))
+def test_deepfm_batches_are_pinned(mix_name, i):
+    """``deepfm-h24``'s batches 0 and 57 in both mixes, as the harness feeds
+    them (``SUT.fields``: the reference's fields, one id a sample)."""
+    from bench import generator
+    bm = registry.load_benchmark(ROOT)
+    cfg = registry.config(ROOT, bm, "deepfm-h24")
+    fields = registry.reference(ROOT, cfg).fields(cfg)
+    b = generator.batch_at(registry.traffic(ROOT, mix_name), fields, 0, 16384,
+                           3_000_000_031, i)
+    h = hashlib.sha256()
+    for f in fields:
+        assert b["fields"][f.name]["ids"].shape == (16384, 1)
+        h.update(np.ascontiguousarray(b["fields"][f.name]["ids"], np.int32).tobytes())
+    assert (h.hexdigest(), hashlib.sha256(b["labels"].tobytes()).hexdigest()) \
+        == PINNED[(mix_name, i)]
+
+
+def test_reference_low_rank_cross_is_the_full_rank_cross_of_the_product():
+    """``low_rank``: each layer's ``(h @ w_down) @ w_up`` is ``h @ w`` with
+    ``w = w_down @ w_up``, at ``highest``; the leaves are named and sized as
+    the program's tree would name a low-rank cross."""
+    import jax
+    ref = registry.reference(ROOT, {"reference": "wdl_reference"})
+    full = json.loads((DATA / "tiny-multihot.json").read_text())
+    low = dict(full, interactions=[dict(full["interactions"][0], low_rank=8)])
+    dd = 8 * 16 + 16
+    spec = {path: (shape, std) for path, shape, std in ref.dense_spec(low)}
+    assert spec[("i0_cross", "l0", "w_down")] == ((dd, 8), pytest.approx(dd ** -0.5))
+    assert spec[("i0_cross", "l0", "w_up")] == ((8, dd), pytest.approx(8 ** -0.5))
+    assert spec[("i0_cross", "l0", "b")] == ((dd,), 0.0)
+    assert ("bottom", "l1", "w") in spec and ("i0_cross", "l0", "w") not in spec
+    seed = 3_000_000_041
+    p_low = ref.init_dense(low, seed)
+    for l in range(3):
+        p_low["i0_cross"][f"l{l}"]["b"] = weights.leaf(seed, 7 + l, (dd,), 0.1)
+    p_full = dict(p_low, i0_cross={
+        k: {"w": jnp.matmul(q["w_down"], q["w_up"], precision="highest"), "b": q["b"]}
+        for k, q in p_low["i0_cross"].items()})
+    x = weights.leaf(seed, 3, (64, 8, 16), 0.5)
+    dense_x = weights.leaf(seed, 4, (64, 13), 1.0)
+    with jax.default_matmul_precision("highest"):
+        a = np.asarray(ref.logits(low, p_low, x, dense_x))
+        b = np.asarray(ref.logits(full, p_full, x, dense_x))
+    assert np.max(np.abs(a - b)) <= 1e-6 * np.max(np.abs(b))
+
+
+@pytest.mark.parametrize("cfg_name", ["tiny-deepfm", "tiny-multihot"])
+def test_reference_pool_sums_each_bag(cfg_name):
+    """``pool`` sums each field's bag; with one id a field it returns the
+    rows bit for bit."""
+    ref = registry.reference(ROOT, {"reference": "wdl_reference"})
+    cfg = json.loads((DATA / f"{cfg_name}.json").read_text())
+    hot = [f.hot for f in ref.fields(cfg)]
+    rows = np.asarray(weights.leaf(3_000_000_051, 5, (16, sum(hot), 4), 1.0))
+    got = np.asarray(ref.pool(cfg, jnp.asarray(rows)))
+    ends = np.cumsum(hot)
+    want = np.stack([rows[:, e - k:e].sum(axis=1) for k, e in zip(hot, ends)], axis=1)
+    if max(hot) == 1:
+        np.testing.assert_array_equal(got, rows)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)

@@ -118,9 +118,9 @@ def test_scope_readers_on_a_hand_trace(metric, want, monkeypatch):
 
 def test_tier_probe_least_bytes():
     # 168,000 distinct ids: 4 B key read + 4 B slot + 1 B hit flag each
-    assert sc.tier_probe_least(168_000) == {"flops": 0, "bytes": 1_512_000}
+    assert flops.tier_probe_least(168_000) == {"flops": 0, "bytes": 1_512_000}
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    assert flops.least_seconds(sc.tier_probe_least(168_000), peak) \
+    assert flops.least_seconds(flops.tier_probe_least(168_000), peak) \
         == 1_512_000 / 819e9
 
 

@@ -35,7 +35,7 @@ def step_hlo():
     cfg = json.loads((DATA / "tiny-deepfm.json").read_text())
     sut = sutmod.build(cfg, registry.reference(ROOT, cfg))
     state = sutmod.init_state(sut, SEED)
-    b = generator.batch_at(registry.traffic(ROOT, "zipf"), sut.field_pairs, 0,
+    b = generator.batch_at(registry.traffic(ROOT, "zipf"), sut.fields, 0,
                            sut.global_batch, SEED, 0)
     b = jax.device_put(b, to_named(sut.mesh, batch_specs(b, sut.axes)))
     text = sut.step.lower(state, b).compile().as_text()
@@ -53,14 +53,20 @@ def test_step_names_every_phase(step_hlo):
     assert {path.split("/")[0] for path in scopes.values()} <= set(obs.TOP_SCOPES)
 
 
-def test_probe_search_loop_and_flush_cond_map_to_their_scopes(step_hlo):
-    """The tier probe's searchsorted is a ``while`` under ``tier_probe``
-    (the owner-start search one under ``partition``), and the step's flush
-    ``conditional`` and the operations of its flush branch are under
-    ``flush``."""
-    _, text, scopes = step_hlo
+def test_indexed_probe_has_no_loop_and_owner_search_and_flush_map_to_their_scopes(step_hlo):
+    """The L1 tier carries its rank index, so ``tier_probe`` holds no
+    ``while``; the owner-start search is still one under ``partition``, and
+    the step's flush ``conditional`` and the operations of its flush branch
+    are under ``flush``."""
+    import jax
+
+    from bench import model as sutmod
+    from bench import weights
+    sut, text, scopes = step_hlo
+    state = jax.eval_shape(sutmod.state_fn(sut), weights.seed_words(SEED))
+    assert all(st.cache.index is not None for st in state["emb"].values())
     loops = {scopes.get(n, "") for n in _ops_by_opcode(text, "while")}
-    assert "sparse_lookup/tier_probe" in loops
+    assert not any(p.endswith("/tier_probe") for p in loops), loops
     assert "sparse_lookup/partition" in loops
     conds = _ops_by_opcode(text, "conditional")
     assert conds and all(scopes.get(n) == "flush" for n in conds)
@@ -164,10 +170,11 @@ chips = len(sut.mesh.devices.flat)
 per = sut.global_batch // chips
 micro = min(sut.plan.microbatch, per)
 out = {"chips": chips, "got": [], "want": []}
+n_dense = cfg.get("n_dense", 0)
 for i in range(2):
-    b = generator.batch_at(mix, sut.field_pairs, 0, sut.global_batch, 3000000021, i)
-    ctx = types.SimpleNamespace(cfg=cfg, mix=mix, field_pairs=sut.field_pairs,
-                                n_dense=0, global_batch=sut.global_batch,
+    b = generator.batch_at(mix, sut.fields, n_dense, sut.global_batch, 3000000021, i)
+    ctx = types.SimpleNamespace(cfg=cfg, mix=mix, fields=sut.fields,
+                                n_dense=n_dense, global_batch=sut.global_batch,
                                 seed=3000000021, first_window_batch=i, n_steps=1)
     want = sc.distinct_rows(ctx, micro)
     bd = jax.device_put(b, to_named(sut.mesh, batch_specs(b, sut.axes)))
@@ -184,12 +191,14 @@ print("RESULT " + json.dumps(out))
 """
 
 
-@pytest.mark.parametrize("cfg_name,chips", [("tiny-deepfm", 1), ("tiny-deepfm-x4", 4)])
+@pytest.mark.parametrize("cfg_name,chips", [("tiny-deepfm", 1), ("tiny-deepfm-x4", 4),
+                                            ("tiny-multihot", 1)])
 def test_distinct_ids_counts_each_chips_distinct_rows(cfg_name, chips):
     """``distinct_ids`` is the sum over chips, groups and micro-batches of
     the distinct rows a lookup worked on: the distinct (field, row) pairs of
-    each chip's share of the batch, each field in its own table, as the
-    benchmark draws them again from the seed (``scopes.distinct_rows``).
+    each chip's share of the batch, each field in its own table and every
+    id of a bag counted, as the benchmark draws them again from the seed
+    (``scopes.distinct_rows``).
     ``scopes.step_text``, which compiles the step from the configuration on
     shapes alone, maps the instructions as the step the run called does. A
     child process on four CPU devices, since the device count is fixed when
