@@ -38,7 +38,10 @@ thing shared with the program is the model's definition, written out here:
 ``readings`` follows the first steps of a run and returns what the
 correctness check compares: the loss of each of the first three steps, each
 leaf's norm of the first gradient and of its change after the third step,
-and each table's norm of its change after the last step followed.
+and each table's norm of its change after the last step followed. It holds
+one table of every row the run touches; each step gathers the rows its
+batch touches, differentiates with respect to them alone and scatters them
+back, so a step's temporaries follow its own distinct rows.
 """
 from __future__ import annotations
 
@@ -191,8 +194,8 @@ def logits(cfg: Dict, params: Dict, x: jnp.ndarray, dense_x) -> jnp.ndarray:
 
 
 def mean_loss(cfg, params, table, idx, dense_x, labels):
-    """``table``: every field's touched rows, one after another, ``[R, d]``;
-    ``idx``: ``[B, T]`` rows of it, each sample's bags one after another."""
+    """``table``: the rows the batch touches, ``[U, d]``; ``idx``: ``[B,
+    T]`` rows of it, each sample's bags one after another."""
     z = logits(cfg, params, pool(cfg, table[idx]), dense_x).astype(jnp.float32)[:, 0]
     y = labels.astype(jnp.float32)
     ls = jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
@@ -200,9 +203,15 @@ def mean_loss(cfg, params, table, idx, dense_x, labels):
 
 
 # ------------------------------------------------------------ one step
-def _step(cfg, params, opt, table, acc, idx, dense_x, labels):
+def _step(cfg, params, opt, table, acc, at, idx, dense_x, labels):
+    """One step on the rows ``at`` of ``table`` that the batch touches, each
+    once (padded with ``len(table)``, which reads a row no sample uses and
+    writes nothing); ``idx``: ``[B, T]`` positions in ``at``. The gradient
+    is taken with respect to those rows alone, so a step's temporaries
+    follow its own distinct rows."""
+    rows = jnp.take(table, at, axis=0, mode="clip")
     loss, (g_p, g_t) = jax.value_and_grad(mean_loss, argnums=(1, 2))(
-        cfg, params, table, idx, dense_x, labels)
+        cfg, params, rows, idx, dense_x, labels)
     t = opt["t"] + 1
     m = jax.tree.map(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g, opt["m"], g_p)
     v = jax.tree.map(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g, opt["v"], g_p)
@@ -212,10 +221,12 @@ def _step(cfg, params, opt, table, acc, idx, dense_x, labels):
         lambda p, m, v: (p - cfg["lr_dense"] * (m / c1) / (jnp.sqrt(v / c2) + ADAM_EPS)
                          ).astype(p.dtype), params, m, v)
     # the gradient of a row is the sum over its duplicates in the step; an
-    # untouched row has a zero gradient and keeps its value and accumulator
-    acc = acc + jnp.mean(g_t * g_t, axis=-1)
-    table = (table - cfg["lr_emb"] * g_t / jnp.sqrt(acc + ADAGRAD_EPS)[:, None]
-             ).astype(table.dtype)
+    # untouched row keeps its value and accumulator
+    a = jnp.take(acc, at, mode="clip") + jnp.mean(g_t * g_t, axis=-1)
+    rows = (rows - cfg["lr_emb"] * g_t / jnp.sqrt(a + ADAGRAD_EPS)[:, None]
+            ).astype(table.dtype)
+    table = table.at[at].set(rows, mode="drop")
+    acc = acc.at[at].set(a, mode="drop")
     return loss, g_p, g_t, params, {"m": m, "v": v, "t": t}, table, acc
 
 
@@ -225,6 +236,7 @@ def _norms(tree) -> Dict[str, jnp.ndarray]:
             for path, leaf in jax.tree_util.tree_leaves_with_path(tree)}
 
 
+BLOCK = 1 << 18  # rows whose initial values are made at a time
 _PROGRAMS: Dict[Tuple, object] = {}
 
 
@@ -232,7 +244,10 @@ def _program(cfg: Dict, dtype, compared: int):
     """One jitted program that follows the steps from the seed: it makes
     the touched rows and the dense leaves, runs the first ``compared`` steps
     one by one and the rest in a loop, and returns the losses and norms. Its
-    inputs carry the seed, so it compiles once for a traffic's row count."""
+    inputs carry the seed, so it compiles once for a traffic's row counts.
+    Besides the table and its accumulator, nothing it holds grows with the
+    rows the run touches: a table's change is taken against its initial
+    rows made again from the seed, ``BLOCK`` rows at a time."""
     key = (json.dumps(cfg, sort_keys=True), jnp.dtype(dtype).name, compared)
     if key in _PROGRAMS:
         return _PROGRAMS[key]
@@ -243,61 +258,77 @@ def _program(cfg: Dict, dtype, compared: int):
     if any(f.dim != d for f in fs):
         raise ValueError("the reference keeps one table of rows for fields of one width")
 
-    def field_norms(x, field_of):
-        sq = jax.ops.segment_sum(jnp.sum(jnp.square(x.astype(jnp.float32)), -1),
-                                 field_of, num_segments=len(fs))
+    def by_field(sq, field_of):
+        return jax.ops.segment_sum(sq, field_of, num_segments=len(fs))
+
+    def named(sq):
         return {f"emb/{n}": v for n, v in zip(names, jnp.sqrt(sq))}
 
-    def run(seed, rows, stream, field_of, idx, dense_x, labels):
-        emb0 = weights.table_rows(seed, stream, rows, d, emb_std(d))
+    def run(seed, rows, stream, field_of, at, idx, dense_x, labels):
+        n = rows.shape[0]
+        table = weights.table_rows(seed, stream, rows, d, emb_std(d)).astype(dtype)
         params0 = init_dense(cfg, seed)
-        table = emb0.astype(dtype)
         params = jax.tree.map(lambda p: p.astype(dtype), params0)
         opt = {"m": jax.tree.map(jnp.zeros_like, params),
                "v": jax.tree.map(jnp.zeros_like, params),
                "t": jnp.zeros((), jnp.int32)}
-        acc = jnp.zeros((table.shape[0],), dtype)
+        acc = jnp.zeros((n,), dtype)
 
         def dx(s):
             return None if dense_x is None else dense_x[s].astype(dtype)
 
+        def table_change(table):
+            blk = min(BLOCK, n)
+
+            def body(i, sq):
+                start = jnp.minimum(i * blk, n - blk)     # the last block may overlap
+                part = start + jnp.arange(blk, dtype=jnp.int32)
+                w0 = weights.table_rows(seed, lax.dynamic_slice_in_dim(stream, start, blk),
+                                        lax.dynamic_slice_in_dim(rows, start, blk),
+                                        d, emb_std(d))
+                w = lax.dynamic_slice_in_dim(table, start, blk).astype(jnp.float32)
+                d2 = jnp.where(part >= i * blk, jnp.sum(jnp.square(w - w0), -1), 0.0)
+                return sq + by_field(d2, lax.dynamic_slice_in_dim(field_of, start, blk))
+            return named(lax.fori_loop(0, -(-n // blk), body,
+                                       jnp.zeros((len(fs),), jnp.float32)))
+
         def change():
             delta = jax.tree.map(lambda p, p0: p.astype(jnp.float32) - p0, params, params0)
-            return {**field_norms(table.astype(jnp.float32) - emb0, field_of),
+            return {**table_change(table),
                     **{f"dense/{k}": v for k, v in _norms(delta).items()}}
 
         losses, grad1 = [], None
         for s in range(compared):
             loss, g_p, g_t, params, opt, table, acc = _step(
-                cfg, params, opt, table, acc, idx[s], dx(s), labels[s])
+                cfg, params, opt, table, acc, at[s], idx[s], dx(s), labels[s])
             losses.append(loss)
             if s == 0:
-                grad1 = {**field_norms(g_t, field_of),
+                sq = jnp.sum(jnp.square(g_t.astype(jnp.float32)), -1)
+                grad1 = {**named(by_field(sq, jnp.take(field_of, at[0], mode="clip"))),
                          **{f"dense/{k}": v for k, v in _norms(g_p).items()}}
         change_c = change()
 
         def later(carry, s):
             params, opt, table, acc = carry
             _, _, _, params, opt, table, acc = _step(
-                cfg, params, opt, table, acc, idx[s], dx(s), labels[s])
+                cfg, params, opt, table, acc, at[s], idx[s], dx(s), labels[s])
             return (params, opt, table, acc), None
 
         (params, opt, table, acc), _ = lax.scan(
             later, (params, opt, table, acc), jnp.arange(compared, labels.shape[0]))
-        tables = field_norms(table.astype(jnp.float32) - emb0, field_of)
-        return jnp.stack(losses), grad1, change_c, tables
+        return jnp.stack(losses), grad1, change_c, table_change(table)
 
     _PROGRAMS[key] = jax.jit(run)
     return _PROGRAMS[key]
 
 
-def readings(cfg: Dict, seed: int, batches: Sequence[Dict], dtype=jnp.float32,
-             compared: int = 3) -> Dict:
-    """Follow ``len(batches)`` steps from the seed's weights. Returns
-    ``{"loss": [each of the first compared steps], "grad1": {leaf: norm},
-    "change": {leaf: norm after the first compared steps}, "tables": {leaf:
-    norm of each table's change after the last step}}``, with leaves named
-    ``emb/<field>`` and ``dense/<path>``."""
+def inputs(cfg: Dict, seed: int, batches: Sequence[Dict]) -> Tuple:
+    """The arguments of ``_program`` for ``batches``: the seed's words; the
+    rows any batch touches, every field's after the one before, with their
+    weight streams and fields; each step's distinct rows among them,
+    ``[S, U]``, padded to the largest step's count ``U`` with one past the
+    last row; each sample's ids as positions in its step's rows, ``[S, B,
+    T]``; the numeric features (or None); and the labels."""
     from bench import weights
     fs = fields(cfg)
     rows, stream, field_of, cols = [], [], [], []
@@ -311,14 +342,30 @@ def readings(cfg: Dict, seed: int, batches: Sequence[Dict], dtype=jnp.float32,
         field_of.append(np.full(uniq.shape, k))
         cols.append(start + np.searchsorted(uniq, hashed))
         start += uniq.shape[0]
-    idx = np.concatenate(cols, axis=-1).astype(np.int32)             # [S, B, T]
+    run_idx = np.concatenate(cols, axis=-1)                         # [S, B, T]
+    steps = [np.unique(x, return_inverse=True) for x in run_idx]
+    at = np.full((len(steps), max(u.size for u, _ in steps)), start, np.int32)
+    for s, (u, _) in enumerate(steps):
+        at[s, :u.size] = u
+    idx = np.stack([inv.reshape(run_idx.shape[1:]) for _, inv in steps]).astype(np.int32)
     dense_x = (np.stack([b["dense"] for b in batches]) if cfg.get("n_dense", 0) else None)
     labels = np.stack([b["labels"] for b in batches])
-    with jax.default_matmul_precision("highest"):
-        losses, grad1, change, tables = _program(cfg, dtype, compared)(
-            weights.seed_words(seed), np.concatenate(rows).astype(np.uint32),
+    return (weights.seed_words(seed), np.concatenate(rows).astype(np.uint32),
             np.concatenate(stream).astype(np.uint32),
-            np.concatenate(field_of).astype(np.int32), idx, dense_x, labels)
+            np.concatenate(field_of).astype(np.int32), at, idx, dense_x, labels)
+
+
+def readings(cfg: Dict, seed: int, batches: Sequence[Dict], dtype=jnp.float32,
+             compared: int = 3, precision: str = "highest") -> Dict:
+    """Follow ``len(batches)`` steps from the seed's weights, in ``dtype``
+    with matmuls at ``precision`` (``jax.default_matmul_precision``).
+    Returns ``{"loss": [each of the first compared steps], "grad1": {leaf:
+    norm}, "change": {leaf: norm after the first compared steps}, "tables":
+    {leaf: norm of each table's change after the last step}}``, with leaves
+    named ``emb/<field>`` and ``dense/<path>``."""
+    with jax.default_matmul_precision(precision):
+        losses, grad1, change, tables = _program(cfg, dtype, compared)(
+            *inputs(cfg, seed, batches))
     return {"loss": [float(x) for x in losses],
             "grad1": {k: float(v) for k, v in grad1.items()},
             "change": {k: float(v) for k, v in change.items()},

@@ -4,7 +4,8 @@
 ``flush_iters``, ``warmup_iters`` and the mesh from the configuration file,
 ``capacity_slack`` at its default), resolves the strategy with
 ``maybe_compile``, and builds ``WDLModel`` and the donating jitted train step
-with ``--fused-kernels auto`` and ``--overlap auto``. ``init_state`` fills
+with ``--fused-kernels auto`` and ``--overlap auto``; where the configuration
+states a ``matmul_precision``, the step is traced at it. ``init_state`` fills
 the program's state in one jitted call with the benchmark's own weights
 (``bench/weights.py``), so that the reference can rebuild them from the seed.
 ``make_readers`` reads what the correctness check compares out of the
@@ -57,6 +58,28 @@ def wdl_config(cfg: Dict, ref):
                      dense_arch=tuple(cfg.get("bottom_mlp", ())))
 
 
+PRECISIONS = ("default", "high", "highest")
+
+
+class AtPrecision:
+    """A jitted step called, and lowered, under ``jax.default_matmul_precision``,
+    so that it is traced at that precision: the precision is part of the
+    key under which JAX keeps the traced step."""
+
+    def __init__(self, step, precision: str):
+        if precision not in PRECISIONS:
+            raise ValueError(f"matmul_precision {precision!r}: one of {PRECISIONS}")
+        self.step, self.precision = step, precision
+
+    def __call__(self, state, batch):
+        with jax.default_matmul_precision(self.precision):
+            return self.step(state, batch)
+
+    def lower(self, *args):
+        with jax.default_matmul_precision(self.precision):
+            return self.step.lower(*args)
+
+
 def build(cfg: Dict, ref, step_wrapper=None) -> SUT:
     from repro.core.packing import make_plan
     from repro.engine import maybe_compile
@@ -83,6 +106,8 @@ def build(cfg: Dict, ref, step_wrapper=None) -> SUT:
     step, sspecs = make_train_step(model, plan, mesh, axes, gb, tcfg, donate=True)
     if step_wrapper is not None:
         step = step_wrapper(step)
+    if "matmul_precision" in cfg:
+        step = AtPrecision(step, cfg["matmul_precision"])
     return SUT(cfg, ref, wdl, plan, mesh, axes, step, sspecs, model, gb)
 
 

@@ -5,7 +5,10 @@
 For each seed, in one process on the chip at the cell's own size, each kind
 against the reference: ``program``, the program's set-up steps through the
 window's step and feed (the lower readings); ``control``, the reference in
-bfloat16 put in the program's place; ``half_batch``, the reference with half
+bfloat16 put in the program's place; ``default_precision``, the reference in
+float32 with its matmuls at JAX's default precision (one bfloat16 pass on
+the TPU) put in the program's place, the control for a configuration that
+states ``matmul_precision: "highest"``; ``half_batch``, the reference with half
 of each batch left out, the mean taken over the rest; ``no_write_back``, the
 program with its tier flushes leaving the master as it was. A step that
 returns its state unchanged reads 1 on ``grad_gap`` and ``change_gap`` by
@@ -22,7 +25,7 @@ import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
-KINDS = ("program", "control", "half_batch", "no_write_back")
+KINDS = ("program", "control", "default_precision", "half_batch", "no_write_back")
 sys.path[0] = str(ROOT)
 sys.path.insert(1, str(ROOT / "src"))
 
@@ -70,6 +73,9 @@ def main(argv=None):
         want = ref.readings(cfg, seed, batches)
         if "control" in args.kinds:
             cands["control"] = ref.readings(cfg, seed, batches, dtype=jnp.bfloat16)
+        if "default_precision" in args.kinds:
+            cands["default_precision"] = ref.readings(cfg, seed, batches,
+                                                      precision="default")
         if "half_batch" in args.kinds:
             cands["half_batch"] = ref.readings(
                 cfg, seed, [faults.half_batch(b) for b in batches])

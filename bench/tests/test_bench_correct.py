@@ -113,19 +113,66 @@ def test_fault_no_write_back_fails(root):
         assert checks[n]["value"] <= checks[n]["limit"], n
 
 
-def test_low_rank_cross_config_stops_at_the_missing_program_leaf(tmp_path):
-    """A configuration with bags, a bottom MLP and a low-rank cross is a new
-    file and a ``BENCHMARK.json`` entry, with no edit to a file of the
-    harness: the reference takes it as it is, and the run stops where the
-    reference's cross leaves are set into the program's state, which has no
-    low-rank cross, naming the leaf the program lacks."""
+def _lowrank_cfg():
+    """``tiny-multihot`` with its cross at rank 8: bags, a bottom MLP and a
+    low-rank cross, as MLPerf's DLRM-DCNv2 has them."""
     cfg = json.loads((DATA / "tiny-multihot.json").read_text())
     cfg["name"] = "tiny-lowrank"
     cfg["interactions"] = [{"kind": "cross", "n_layers": 3, "low_rank": 8}]
+    return cfg
+
+
+def test_low_rank_cross_config_needs_no_harness_edit(tmp_path):
+    """A configuration with bags, a bottom MLP and a low-rank cross is a new
+    file and a ``BENCHMARK.json`` entry, with no edit to a file of the
+    harness: the registry finds it, the generator draws its bags, the
+    reference names its cross leaves as a program's tree would and follows
+    its steps, and the dense FLOPs count the cross at its rank."""
+    from bench import flops
+    from bench.model import wdl_config
+    cfg = _lowrank_cfg()
     r = _checkout(tmp_path, {"tiny-lowrank": "tiny-lowrank.zipf"})
     (r / "bench/tests/data/tiny-lowrank.json").write_text(json.dumps(cfg))
+    bm = registry.load_benchmark(r)
+    assert registry.config(r, bm, "tiny-lowrank") == cfg
     ref = registry.reference(r, cfg)
-    assert ((("i0_cross", "l0", "w_down"), (144, 8)) in
-            [(path, shape) for path, shape, _ in ref.dense_spec(cfg)])
+    spec = {path: shape for path, shape, _ in ref.dense_spec(cfg)}
+    for l in range(3):
+        assert spec[("i0_cross", f"l{l}", "w_down")] == (144, 8)
+        assert spec[("i0_cross", f"l{l}", "w_up")] == (8, 144)
+        assert spec[("i0_cross", f"l{l}", "b")] == (144,)
+        assert ("i0_cross", f"l{l}", "w") not in spec
+    assert [dict(i.kwargs) for i in wdl_config(cfg, ref).interactions] == \
+        [{"n_layers": 3, "low_rank": 8}]
+    mix = registry.traffic(r, "zipf")
+    seed = 3_000_000_017
+    batches = [generator.batch_at(mix, ref.fields(cfg), cfg["n_dense"], cfg["global_batch"],
+                                  seed, i) for i in range(int(cfg["flush_iters"]) + 1)]
+    assert batches[0]["fields"]["cat_2"]["ids"].shape == (cfg["global_batch"], 8)
+    got = ref.readings(cfg, seed, batches)
+    assert len(got["loss"]) == harness.COMPARED_STEPS
+    assert all(0 < x < 10 for x in got["loss"])
+    for l in range(3):
+        for leaf in ("w_down", "w_up"):
+            assert got["grad1"][f"dense/i0_cross/l{l}/{leaf}"] > 0
+            assert got["change"][f"dense/i0_cross/l{l}/{leaf}"] > 0
+    assert set(got["tables"]) == {f"emb/{f.name}" for f in ref.fields(cfg)}
+    full = dict(cfg, interactions=[{"kind": "cross", "n_layers": 3}])
+    b, dd = cfg["global_batch"], 144
+    assert flops.dense_step_flops(full, b) - flops.dense_step_flops(cfg, b) == \
+        3 * 6 * b * dd * dd - 3 * 12 * b * dd * 8
+
+
+def test_set_names_a_missing_dense_leaf():
+    """Where the program's dense tree lacks a leaf the reference sets, the
+    run stops naming it; a leaf of another shape is named with both."""
+    from bench.model import _set
+    tree = {"i0_cross": {"l0": {"w": jnp.zeros((144, 144)), "b": jnp.zeros((144,))}}}
     with pytest.raises(ValueError, match="no dense leaf i0_cross/l0/w_down"):
-        _run(r, "tiny-lowrank.zipf")
+        _set(tree, ("i0_cross", "l0", "w_down"), jnp.ones((144, 8)))
+    with pytest.raises(ValueError, match="no dense leaf i1_cross/l0/b"):
+        _set(tree, ("i1_cross", "l0", "b"), jnp.ones((144,)))
+    with pytest.raises(ValueError, match=r"i0_cross/l0/b: program shape \(144,\)"):
+        _set(tree, ("i0_cross", "l0", "b"), jnp.ones((145,)))
+    _set(tree, ("i0_cross", "l0", "b"), jnp.ones((144,)))
+    assert float(tree["i0_cross"]["l0"]["b"].sum()) == 144.0

@@ -187,3 +187,38 @@ def test_reference_pool_sums_each_bag(cfg_name):
     if max(hot) == 1:
         np.testing.assert_array_equal(got, rows)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def _lowered_step(cfg):
+    """The text of the benchmark's step for ``cfg`` lowered on its shapes,
+    and that of the program's jitted step it wraps, lowered outside any
+    precision context."""
+    import jax
+
+    from bench import generator
+    from bench import model as sutmod
+    from repro.dist.sharding import batch_specs, to_named
+    raw = []
+    sut = sutmod.build(cfg, registry.reference(ROOT, cfg),
+                       step_wrapper=lambda s: raw.append(s) or s)
+    state = jax.eval_shape(sutmod.state_fn(sut), weights.seed_words(3_000_000_061))
+    b = generator.batch_at(registry.traffic(ROOT, "zipf"), sut.fields, cfg.get("n_dense", 0),
+                           sut.global_batch, 3_000_000_061, 0)
+    b = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+                     b, to_named(sut.mesh, batch_specs(b, sut.axes)))
+    return sut.step.lower(state, b).as_text(), raw[0].lower(state, b).as_text()
+
+
+def test_matmul_precision_traces_the_step_at_it():
+    """``matmul_precision: "highest"`` puts HIGHEST on every dot of the
+    step and changes nothing else; without the key the step is the
+    program's own, traced at the default precision."""
+    cfg = json.loads((DATA / "tiny-deepfm.json").read_text())
+    plain, plain_raw = _lowered_step(cfg)
+    high, high_raw = _lowered_step(dict(cfg, matmul_precision="highest"))
+    assert plain == plain_raw == high_raw and "HIGHEST" not in plain
+    dots = [line for line in high.splitlines() if "dot_general" in line]
+    assert dots and all("precision = [HIGHEST, HIGHEST]" in line for line in dots)
+    assert high.replace("HIGHEST", "DEFAULT") == plain
+    with pytest.raises(ValueError, match="matmul_precision 'bf16'"):
+        _lowered_step(dict(cfg, matmul_precision="bf16"))
